@@ -92,4 +92,7 @@ def main(rows=None):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

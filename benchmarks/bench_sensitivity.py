@@ -89,4 +89,7 @@ def main(rows=None, pretrain_steps=150, finetune_steps=60):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

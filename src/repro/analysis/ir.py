@@ -1,13 +1,11 @@
-"""Shared IR inspection utilities: HLO-text parsing + jaxpr walking + the
-jax 0.4.x `cost_analysis` compat shim.
+"""Shared IR inspection utilities: HLO-text parsing + jaxpr walking.
 
 Two consumers (kept deliberately in one place — ISSUE 6 satellite):
 
 - `repro.launch.hlo_analysis` — the trip-count-aware roofline profiler
   parses post-compile HLO text through `parse_hlo`/`symbol_table`.
 - `repro.analysis.jaxpr_audit` — the serving-contract audit walks jaxprs
-  (`iter_eqns`) and lowered StableHLO (donation aliasing), and normalizes
-  `compiled.cost_analysis()` through `xla_cost_dict`.
+  (`iter_eqns`) and lowered StableHLO (donation aliasing).
 """
 from __future__ import annotations
 
@@ -15,7 +13,7 @@ import dataclasses
 import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
-import jax.core as jax_core
+import jax.extend.core as jax_core
 
 # ---------------------------------------------------------------------------
 # HLO text parsing (shapes, instructions, computations)
@@ -115,28 +113,6 @@ def operand_names(rest: str) -> List[str]:
             depth -= 1
         token += ch
     return re.findall(r"%([\w\.\-]+)", token)
-
-
-# ---------------------------------------------------------------------------
-# compiled.cost_analysis() compat (jax ≤0.4.x returns a list, newer a dict)
-# ---------------------------------------------------------------------------
-
-def xla_cost_dict(compiled_or_cost) -> dict:
-    """Normalize `compiled.cost_analysis()` to one flat dict.
-
-    Accepts either the compiled executable or the raw cost_analysis result.
-    jax ≤0.4.x returns a list with one entry per computation (the entry
-    program first); newer jax returns the dict directly; some versions
-    return None for unsupported backends.
-    """
-    cost = compiled_or_cost
-    if hasattr(cost, "cost_analysis"):
-        cost = cost.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
 
 
 # ---------------------------------------------------------------------------

@@ -20,38 +20,11 @@ import math
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# -- jax version compatibility ---------------------------------------------
-# The suite spans jax 0.4.x (no mesh axis_types, no jax.shard_map,
-# AbstractMesh((name, size), ...) pairs) and current jax (axis_types on
-# make_mesh, jax.shard_map, AbstractMesh(shape, names)). Every mesh/shard_map
-# construction in src/ and tests/ goes through these three helpers.
-
 def make_mesh(shape, axes, *, devices=None):
-    """jax.make_mesh across versions; Auto axis_types where supported."""
+    """jax.make_mesh with every axis Auto (GSPMD propagates shardings)."""
     kwargs = {} if devices is None else {"devices": devices}
-    try:
-        return jax.make_mesh(
-            shape, axes, **kwargs,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (TypeError, AttributeError):
-        return jax.make_mesh(shape, axes, **kwargs)
-
-
-def make_abstract_mesh(shape, axes):
-    """jax.sharding.AbstractMesh across versions (carries shape/axis_names
-    without real devices — used by spec tests)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:                       # jax<=0.4.x: (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map (new) or jax.experimental.shard_map (0.4.x)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.make_mesh(shape, axes, **kwargs,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 LOGICAL_AXIS_RULES = {
@@ -186,12 +159,10 @@ def set_active_mesh(mesh):
 
 
 def constrain(x, axes, mesh=None):
-    """Activation sharding constraint by logical names (no-op outside mesh)."""
+    """Activation sharding constraint by logical names (no-op outside mesh).
+    A constraint the mesh cannot take raises: it never silently vanishes."""
     mesh = mesh or _ACTIVE_MESH
     if mesh is None:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, logical_to_pspec(axes, mesh, x.shape))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, logical_to_pspec(axes, mesh, x.shape)))

@@ -19,8 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-from repro.kernels.tpu_compat import CompilerParams as _CompilerParams
-from repro.kernels.tpu_compat import pad_to_multiple as _pad_axis
+from repro.kernels.padding import pad_to_multiple as _pad_axis
 
 
 BM, BN, BK = 128, 128, 512
@@ -67,7 +66,7 @@ def add_matmul_pallas(x, b, *, bm=BM, bn=BN, bk=BK, interpret=False):
         out_specs=pl.BlockSpec((1, bm, bn), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, b)

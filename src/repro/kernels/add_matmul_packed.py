@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-from repro.kernels.tpu_compat import CompilerParams as _CompilerParams
-from repro.kernels.tpu_compat import pad_to_multiple as _pad_axis
+from repro.kernels.padding import pad_to_multiple as _pad_axis
 
 
 BM, BN, BK8 = 128, 128, 64          # BK8 packed rows = 512 logical K rows
@@ -45,11 +44,14 @@ def _kernel(x_ref, p_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    p = p_ref[0]                                      # (BK8, BN) uint8
+    # Unpack in int32 and go through f32 to bf16: Mosaic lowers neither
+    # 8-bit vector shifts nor a direct uint8 → bf16 cast.
+    p = p_ref[0].astype(jnp.int32)                    # (BK8, BN), 0..255
     k8, bn = p.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (k8, 8, bn), 1)
-    bits = (p[:, None, :] >> shifts) & jnp.uint8(1)
-    b = (bits.astype(jnp.bfloat16) * 2.0 - 1.0).reshape(k8 * 8, bn)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (k8, 8, bn), 1)
+    bits = (p[:, None, :] >> shifts) & 1
+    b = (bits.astype(jnp.float32) * 2.0 - 1.0).reshape(k8 * 8, bn)
+    b = b.astype(jnp.bfloat16)
     acc_ref[...] += jnp.dot(x_ref[0].astype(jnp.bfloat16), b,
                             preferred_element_type=jnp.float32)
 
@@ -84,7 +86,7 @@ def add_matmul_packed_pallas(x, packed, *, bm=BM, bn=BN, bk8=BK8,
         out_specs=pl.BlockSpec((1, bm, bn), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -36,8 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams as _CompilerParams
 
 # Upper bound on padded sequence length: q, k, v, codes and out all live in
 # VMEM simultaneously (~6 · N · 128 lanes · 4 B ≈ 12 MB at N=4096). Longer
@@ -104,7 +104,7 @@ def bidir_binary_attention_pallas(q, k, v, *, dk_true=None, n_true=None,
         ],
         out_specs=pl.BlockSpec((1, n, dv), lambda gg: (gg, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, n, dv), v.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, k, v)
 

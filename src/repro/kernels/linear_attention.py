@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-from repro.kernels.tpu_compat import CompilerParams as _CompilerParams
 
 
 CHUNK = 256
@@ -86,8 +85,8 @@ def _make_kernel(dk_true: int, chunk: int, n_true: int, return_state: bool):
             # Same (gg, 0) block every chunk step; the last write survives —
             # the final carry leaves VMEM exactly once per (batch*head).
             kv_out[0] = kv_ref[...]
-            ksum_out[...] = ksum_ref[...]
-            vsum_out[...] = vsum_ref[...]
+            ksum_out[0] = ksum_ref[...]
+            vsum_out[0] = vsum_ref[...]
 
     return kernel
 
@@ -118,14 +117,16 @@ def binary_linear_attention_pallas(q, k, v, *, dk_true=None, chunk=CHUNK,
         out_specs = [
             out_specs,
             pl.BlockSpec((1, dk, dv), lambda gg, i: (gg, 0, 0)),
-            pl.BlockSpec((1, dk), lambda gg, i: (gg, 0)),
-            pl.BlockSpec((1, dv), lambda gg, i: (gg, 0)),
+            # (G, 1, D) so each block's last two dims equal the array's —
+            # a (1, D) block over (G, D) breaks the TPU (8, 128) tiling rule.
+            pl.BlockSpec((1, 1, dk), lambda gg, i: (gg, 0, 0)),
+            pl.BlockSpec((1, 1, dv), lambda gg, i: (gg, 0, 0)),
         ]
         out_shape = [
             out_shape,
             jax.ShapeDtypeStruct((g, dk, dv), jnp.float32),
-            jax.ShapeDtypeStruct((g, dk), jnp.float32),
-            jax.ShapeDtypeStruct((g, dv), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1, dk), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1, dv), jnp.float32),
         ]
     return pl.pallas_call(
         _make_kernel(dk_true, chunk, n_true, return_state),
@@ -142,7 +143,7 @@ def binary_linear_attention_pallas(q, k, v, *, dk_true=None, chunk=CHUNK,
             pltpu.VMEM((1, dk), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -55,7 +55,7 @@ def set_default_impl(impl):
     _IMPL_OVERRIDE = impl
 
 
-from repro.kernels.tpu_compat import pad_to_multiple as _pad_to
+from repro.kernels.padding import pad_to_multiple as _pad_to
 
 
 def sublane_block(m: int, cap: int) -> int:
@@ -291,8 +291,8 @@ def binary_linear_attention_fused(q, k, v, *, chunk=None, impl=None,
     out, kv, ksum, vsum = res
     state = {
         "kv": kv[:, :dk, :dv].reshape(b, h, dk, dv),
-        "ksum": ksum[:, :dk].reshape(b, h, dk),
-        "vsum": vsum[:, :dv].reshape(b, h, dv),
+        "ksum": ksum[:, 0, :dk].reshape(b, h, dk),
+        "vsum": vsum[:, 0, :dv].reshape(b, h, dv),
         "count": jnp.asarray(float(n), jnp.float32),
     }
     return out[:, :n, :dv].reshape(b, h, n, dv), state

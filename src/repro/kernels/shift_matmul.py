@@ -20,8 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-from repro.kernels.tpu_compat import CompilerParams as _CompilerParams
-from repro.kernels.tpu_compat import pad_to_multiple as _pad_axis
+from repro.kernels.padding import pad_to_multiple as _pad_axis
 
 
 from repro.core.quant import P_MIN
@@ -31,12 +30,17 @@ BM, BN, BK = 128, 128, 512
 
 
 def _assemble_bf16(sp):
-    """packed int8 (sign|P+64) → exact bf16 s*2^P, integer ops only."""
-    u = jax.lax.bitcast_convert_type(sp, jnp.uint8)
-    sign = (u >> 7).astype(jnp.uint16) << 15
-    p = (u & 0x7F).astype(jnp.int32) + P_MIN          # P in [-64, 63]
-    exp_field = (p + 127).astype(jnp.uint16) << 7     # bf16 exponent, mantissa 0
-    return jax.lax.bitcast_convert_type(sign | exp_field, jnp.bfloat16)
+    """packed int8 (sign|P+64) → exact bf16 s*2^P, integer ops only.
+
+    The decode runs in 32-bit integers: Mosaic cannot lower shifts of 8- or
+    16-bit vectors. The bits are assembled as an f32 (same sign and exponent
+    layout as bf16, zero mantissa), so narrowing to bf16 is exact."""
+    u = sp.astype(jnp.int32) & 0xFF                   # sign-extend, re-mask
+    sign = (u >> 7) << 31
+    p = (u & 0x7F) + P_MIN                            # P in [-64, 63]
+    exp_field = (p + 127) << 23                       # f32 exponent, mantissa 0
+    w = jax.lax.bitcast_convert_type(sign | exp_field, jnp.float32)
+    return w.astype(jnp.bfloat16)
 
 
 def _shift_matmul_kernel(x_ref, sp_ref, o_ref, acc_ref):
@@ -80,7 +84,7 @@ def shift_matmul_pallas(x, w_packed, *, bm=BM, bn=BN, bk=BK, interpret=False):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed)

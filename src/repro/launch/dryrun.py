@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST run before any other import — jax locks the device
+The lines above MUST run before any other import — jax locks the device
 count at first init. Do not set that flag globally (smoke tests and benches
-must see 1 device).
+must see 1 device). The dry-run is a CPU tool on 512 fake host devices:
+JAX_PLATFORMS=cpu keeps it, and every child `--all` spawns (they inherit
+the environment), off an attached accelerator, which belongs to one
+process at a time.
 
 Per cell:
   train_4k    → jax.jit(train_step)   (state donated, microbatched)
@@ -36,7 +40,6 @@ from repro.configs import shapes as shp
 from repro.configs.base import TrainConfig
 from repro.configs.registry import get_config, list_archs
 from repro.distributed import sharding as shard_lib
-from repro.analysis import ir
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.nn.model import LanguageModel
@@ -219,7 +222,7 @@ def lower_cell(arch, shape_name, mesh_kind, policy=None, n_micro=None,
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    xla_cost = ir.xla_cost_dict(compiled)
+    xla_cost = compiled.cost_analysis() or {}
     hlo_cost = hlo_analysis.analyze(compiled.as_text())
 
     tokens = spec.global_batch * (spec.seq_len if spec.kind != "decode" else 1)
@@ -359,4 +362,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -31,9 +31,9 @@ definitions and computation signatures, so operand sizes resolve across
 regions. Post-SPMD HLO is the per-device program: all numbers are per-chip.
 
 The HLO-text parsing layer (shape/instruction/computation grammar, the
-name→type symbol table, the jax cost_analysis list-vs-dict compat) lives in
-`repro.analysis.ir` and is shared with the serving-contract static analyzer
-(`repro.analysis`); this module keeps only the roofline-specific cost model
+name→type symbol table) lives in `repro.analysis.ir` and is shared with the
+serving-contract static analyzer (`repro.analysis`); this module keeps only
+the roofline-specific cost model
 (trip counts, dot/conv flops, the write-once byte model).
 """
 from __future__ import annotations
@@ -48,7 +48,7 @@ from repro.analysis.ir import (Computation, Instr, nbytes as _nbytes,  # noqa: F
                                operand_names as _operand_names,
                                parse_hlo, parse_shapes as _parse_shapes,
                                symbol_table as _symbol_table,
-                               xla_cost_dict, CALLS_RE as _CALLS_RE)
+                               CALLS_RE as _CALLS_RE)
 
 _CONST_RE = re.compile(r"constant\((\d+)\)")
 

@@ -128,6 +128,7 @@ class BucketedViTEngine:
         # unless a future output actually matches an input buffer.
         self.donate_argnums = ()
         jit_kw = {}
+        rows = None
         if mesh is not None:
             from repro.distributed import sharding as shd
             # Data-parallel arm: rows over the mesh's batch axes, logits
@@ -135,6 +136,19 @@ class BucketedViTEngine:
             # rule, reused verbatim by the vision serving path.
             jit_kw = dict(in_shardings=shd.batch_sharding(mesh, rank=4),
                           out_shardings=shd.batch_sharding(mesh, rank=2))
+            rows = jit_kw["in_shardings"].spec
+
+        def per_shard(f, *replicated):
+            """Run f on each device's rows (shard_map). The compiler cannot
+            partition a Mosaic kernel by itself, and the forward is row-local
+            (see the batch-invariance contract), so every device runs the
+            one-device program on its shard, weights replicated. The
+            kernels' out_shapes carry no varying-axes (vma) type, so the
+            check is off."""
+            if rows is None:
+                return f
+            return jax.shard_map(f, mesh=mesh, in_specs=(*replicated, rows),
+                                 out_specs=rows, check_vma=False)
         if freeze:
             # The MoE dispatch routes one group per image row, so the only
             # token count it ever plans capacity for is the per-image patch
@@ -157,8 +171,8 @@ class BucketedViTEngine:
                                    tune=tune_)
 
             self._fwd = fwd
-            self._call = jax.jit(fwd, donate_argnums=self.donate_argnums,
-                                 **jit_kw)
+            self._call = jax.jit(per_shard(fwd),
+                                 donate_argnums=self.donate_argnums, **jit_kw)
         else:
             self.plan = None
 
@@ -178,7 +192,8 @@ class BucketedViTEngine:
                 jit_kw["in_shardings"] = (shd.replicated(mesh),
                                           jit_kw["in_shardings"])
             self._fwd = fwd
-            fwd_j = jax.jit(fwd, **jit_kw)
+            fwd_j = jax.jit(per_shard(fwd, jax.sharding.PartitionSpec()),
+                            **jit_kw)
             self._call = lambda images: fwd_j(self.params, images)
 
     def bucket_for(self, n: int) -> int:

@@ -104,7 +104,7 @@ def test_jaxpr_planted_float_scatter_add():
 
 def test_jaxpr_planted_f64():
     from analysis_fixtures import planted_jaxpr as p
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = _fixture_jaxpr(p.f64_promotion, jnp.zeros((4,), jnp.float32))
     assert "JX002" in _rules(audit_closed_jaxpr(closed, "fixture"))
 

@@ -32,7 +32,7 @@ def test_logical_rules_cover_required_axes():
 
 def test_pspec_divisibility_fallback():
     # AbstractMesh carries shape/axis_names without requiring real devices.
-    mesh = sl.make_abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     # indivisible dims fall back to replication
     spec = logical_to_pspec(("batch", "vocab"), mesh, (3, 5))
     assert all(s is None for s in spec) or len(spec) == 0
@@ -100,8 +100,8 @@ def test_compressed_psum_matches_plain_psum():
             return reduced, exact, residual
 
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
-        r, e, res = jax.jit(sl.shard_map(f, mesh=mesh, in_specs=P("pod"),
-                                         out_specs=P("pod")))(x)
+        r, e, res = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                          out_specs=P("pod")))(x)
         rel = float(jnp.max(jnp.abs(r - e)) / (jnp.max(jnp.abs(e)) + 1e-9))
         # int8 quantization: ~1% relative error on the reduction
         assert rel < 0.05, rel

@@ -12,8 +12,6 @@ from repro.launch import hlo_analysis as H
 def _flops(fn, *args):
     comp = jax.jit(fn).lower(*args).compile()
     cost = comp.cost_analysis()
-    if isinstance(cost, list):        # jax<=0.4.x: one entry per computation
-        cost = cost[0]
     return H.analyze(comp.as_text()), cost
 
 

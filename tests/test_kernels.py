@@ -101,8 +101,36 @@ def test_add_matmul_bitpacked_sweep(g, m, k, n):
     _close(ops.add_matmul_bitpacked(x, packed, "xla"), out_ref, tol=1e-3)
 
 
+ALL_BYTES = np.arange(256, dtype=np.uint8)
+
+
+def test_shift_kernel_decode_bitexact_all_bytes():
+    """The kernel's 32-bit decode of every packed byte equals
+    po2_weight_from_packed bit for bit: x = I picks each weight row out of
+    the MXU product unchanged (one nonzero term per output)."""
+    w_packed = jnp.asarray(ALL_BYTES.view(np.int8).reshape(256, 1))
+    y = ops.shift_matmul(jnp.eye(256, dtype=jnp.float32), w_packed,
+                         "interpret")
+    want = quant.po2_weight_from_packed(w_packed, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(y).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+def test_packed_bits_kernel_decode_bitexact_all_bytes():
+    """The kernel's int32 bit unpack of every byte equals unpack_bits:
+    x = I (8 logical K rows) reads the ±1 operand back out exactly."""
+    from repro.kernels.add_matmul_packed import unpack_bits
+
+    packed = jnp.asarray(ALL_BYTES.reshape(1, 1, 256))
+    y = ops.add_matmul_bitpacked(jnp.eye(8, dtype=jnp.float32)[None],
+                                 packed, "interpret")
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(unpack_bits(packed)))
+
+
 @pytest.mark.parametrize("b,h,n,dk,dv", [(1, 2, 256, 16, 16),
-                                         (2, 1, 300, 24, 20)])
+                                         (2, 1, 300, 24, 20),
+                                         (2, 3, 196, 64, 64)])
 def test_linattn_kernel_returns_final_carry(b, h, n, dk, dv):
     """return_state must emit the exact recurrent carry (kv, ksum, vsum) the
     O(1) decode step resumes from — including when N is padded to the chunk."""
