@@ -4,7 +4,6 @@ per-PR trajectory, next to BENCH_serve.json's LM numbers.
 
     PYTHONPATH=src python benchmarks/bench_vit.py [--batch 32]
     PYTHONPATH=src python benchmarks/bench_vit.py --no-freeze   # A/B arm
-    PYTHONPATH=src python benchmarks/bench_vit.py --breakdown   # per-component
     PYTHONPATH=src python benchmarks/bench_vit.py --impl interpret
     PYTHONPATH=src python benchmarks/bench_vit.py --tune TUNE_kernels.json
 
@@ -26,12 +25,9 @@ Reported per policy: batch latency (median), throughput, analytic per-image
 energy (paper Tab. 1 unit energies + DRAM movement), the engine's compile
 counts (recompiles_after_warmup must be 0 — gated in CI), the freeze state,
 and the latency ratio vs the dense arm (`shiftadd_vs_dense_latency` is the
-paper's crossover, gated ≤ 1.0 in the acceptance criteria). `--breakdown`
-adds measured attention / MLP-MoE / dispatch / other component rows in
-bench_breakdown.py's table style, plus — on MoE arms — `dispatch_global`
-(the legacy flattened-co-batch dispatch) and `dispatch_delta`
-(per-image − global), so the hot-path cost of the batch-invariant
-per-image capacity dispatch stays visible in the BENCH_vit.json trajectory.
+paper's crossover, gated ≤ 1.0 in the acceptance criteria). Device time
+per model component comes from a device trace read by the model's named
+scopes (bench/run.py --trace 1), not from this host-clock sweep.
 """
 from __future__ import annotations
 
@@ -138,8 +134,6 @@ def main(rows=None):
                     help="run the interleaved frozen-vs-live A/B of the "
                          "shiftadd arm instead of the policy sweep (the CI "
                          "freeze gate's measurement; noise-robust)")
-    ap.add_argument("--breakdown", action="store_true",
-                    help="add measured attention/MLP-MoE/dispatch/other rows")
     ap.add_argument("--out", default=None,
                     help="output path (default: BENCH_vit.json, or "
                          "BENCH_vit_freeze_ab.json under --ab-freeze)")
@@ -175,7 +169,7 @@ def main(rows=None):
         return
     rec = policy_sweep(cfg, batch=args.batch, iters=args.iters,
                        freeze=not args.no_freeze, impl=args.impl,
-                       tune=tune, breakdown=args.breakdown)
+                       tune=tune)
     if not args.skip_pallas_arm:
         rec["pallas_arm"] = pallas_arm(cfg, batch=args.batch,
                                        iters=args.iters, tune=tune)
@@ -195,33 +189,6 @@ def main(rows=None):
               f"dense energy, frozen={r['frozen']}, buckets={r['buckets']}, "
               f"waste={r['padding_waste']:.3f}, "
               f"recompiles={r['recompiles_after_warmup']})")
-    if args.breakdown:
-        # bench_breakdown.py row style: name, microseconds, notes. The
-        # additive split is attention + mlp_moe + other; dispatch is a
-        # SUBSET of mlp_moe (routing machinery re-measured in isolation),
-        # so its row is annotated as such rather than given a fraction.
-        # dispatch_global re-measures the LEGACY flattened-co-batch
-        # dispatch; the delta row is what the per-image batch-invariance
-        # refactor costs (+) or saves (−) on the hot path per batch.
-        for name, r in rec["policies"].items():
-            bd = r["breakdown"]
-            for comp in ("attention", "mlp_moe", "other"):
-                frac = bd[f"{comp}_s"] / bd["total_s"] if bd["total_s"] else 0
-                print(",".join(str(c) for c in (
-                    f"serve_{name}_{comp}", bd[f"{comp}_s"] * 1e6,
-                    f"fraction_of_total={frac:.2f}")))
-            print(",".join(str(c) for c in (
-                f"serve_{name}_dispatch", bd["dispatch_s"] * 1e6,
-                "subset_of_mlp_moe;per_image_capacities")))
-            if bd["dispatch_global_s"]:
-                print(",".join(str(c) for c in (
-                    f"serve_{name}_dispatch_global",
-                    bd["dispatch_global_s"] * 1e6,
-                    "legacy_flattened_co_batch_capacities")))
-                print(",".join(str(c) for c in (
-                    f"serve_{name}_dispatch_delta",
-                    bd["dispatch_delta_s"] * 1e6,
-                    "per_image_minus_global")))
     if "shiftadd_vs_dense_latency" in rec:
         print(f"shiftadd vs dense latency: "
               f"{rec['shiftadd_vs_dense_latency']:.3f}x (frozen={rec['frozen']})")

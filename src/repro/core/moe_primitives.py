@@ -301,25 +301,20 @@ class MoEPrimitives:
         _, top1, gate = self._gates(clean_logits, clean_logits)
         return top1, gate[..., 0].astype(jnp.float32)
 
-    def _dispatch_tokens(self, params, x, grouping="image"):
+    def _dispatch_tokens(self, params, x):
         """Shared serving front half: group → route (clean argmax) →
         gather-ordered dispatch. Returns (buf, info, segments, ungroup) with
         `segments` the per-expert static views of the buffer. Single home so
-        `infer` and the breakdown probe `dispatch_only` can never diverge on
-        the dispatch they measure/serve.
+        `infer` and the expert telemetry probe (serve.telemetry) can never
+        diverge on the dispatch they serve/measure.
 
-        grouping="image" (the serving default) plans capacity PER BATCH ROW
-        (`nn.dispatch.group_rows`): each image competes only with itself for
-        expert slots, so per-image outputs are independent of co-batching —
-        the batch-invariance contract. grouping="flat" is the legacy
-        flattened-co-batch grouping (`group_tokens`), kept ONLY as the A/B
-        arm of the dispatch-cost breakdown benchmark."""
-        from repro.nn.dispatch import (dispatch_infer, group_rows,
-                                       group_tokens)
+        Capacity is planned PER BATCH ROW (`nn.dispatch.group_rows`): each
+        image competes only with itself for expert slots, so per-image
+        outputs are independent of co-batching — the batch-invariance
+        contract."""
+        from repro.nn.dispatch import dispatch_infer, group_rows
 
-        assert grouping in ("image", "flat"), grouping
-        group = group_rows if grouping == "image" else group_tokens
-        xg, ungroup = group(x, self.d_model)
+        xg, ungroup = group_rows(x, self.d_model)
         _, s, _ = xg.shape
         top1, gate = self._route_infer(params, xg)
         caps, offsets = self.capacity_plan(s)
@@ -347,27 +342,23 @@ class MoEPrimitives:
         neighbors it is batched with, its row position, or batch padding
         (no token ever competes with another image's tokens for capacity).
         Returns y only.
+
+        Named scopes: `moe_dispatch` (router and dispatch), one
+        `expert_<kind>` per expert, `moe_combine`.
         """
         from repro.nn.dispatch import combine_infer
 
-        _, info, segments, ungroup = self._dispatch_tokens(params, x)
-        outs = [expert(params["experts"][i], seg, impl=impl, tune=tune)
-                if getattr(expert, "accepts_impl", False)
-                else expert(params["experts"][i], seg)
-                for i, (expert, seg) in enumerate(zip(self.experts, segments))]
-        return ungroup(combine_infer(outs, info)).astype(x.dtype)
-
-    def dispatch_only(self, params, x, grouping="image"):
-        """Routing + dispatch + combine with identity experts — isolates the
-        dispatch machinery's cost for the component-breakdown benchmark.
-        grouping="flat" measures the legacy flattened-co-batch dispatch so
-        the per-image refactor's hot-path cost stays visible in the bench
-        trajectory (BENCH_vit.json's dispatch rows)."""
-        from repro.nn.dispatch import combine_infer
-
-        _, info, segments, ungroup = self._dispatch_tokens(params, x,
-                                                           grouping=grouping)
-        return ungroup(combine_infer(segments, info)).astype(x.dtype)
+        with jax.named_scope("moe_dispatch"):
+            _, info, segments, ungroup = self._dispatch_tokens(params, x)
+        outs = []
+        for i, (expert, seg) in enumerate(zip(self.experts, segments)):
+            with jax.named_scope(f"expert_{self.expert_kinds[i]}"):
+                outs.append(
+                    expert(params["experts"][i], seg, impl=impl, tune=tune)
+                    if getattr(expert, "accepts_impl", False)
+                    else expert(params["experts"][i], seg))
+        with jax.named_scope("moe_combine"):
+            return ungroup(combine_infer(outs, info)).astype(x.dtype)
 
     def __call__(self, params, x, train=True, rng=None):
         """x: (..., d_model). Tokens are routed in sharded groups

@@ -148,14 +148,23 @@ class TransformerBlock:
         block forward is batch-invariant per row. Returns x only — the
         serving engines jit this, typically closed over a core.deploy
         DeployPlan's frozen params so no per-call weight decode survives in
-        the compiled program."""
-        h = self.norm1(params["norm1"], x)
-        mix = self._infer_mixer(params, h, positions, impl=impl, tune=tune)
-        if self.parallel:
-            return x + mix + self._infer_feed(params, h, impl=impl, tune=tune)
-        x = x + mix
-        h2 = self.norm2(params["norm2"], x)
-        return x + self._infer_feed(params, h2, impl=impl, tune=tune)
+        the compiled program.
+
+        Named scopes `mixer` (norm1, the mixer, its residual add) and `feed`
+        (norm2, the MLP or MoE, its residual add) cover every op of the
+        block."""
+        with jax.named_scope("mixer"):
+            h = self.norm1(params["norm1"], x)
+            mix = self._infer_mixer(params, h, positions, impl=impl,
+                                    tune=tune)
+            if not self.parallel:
+                x = x + mix
+        with jax.named_scope("feed"):
+            if self.parallel:
+                return x + mix + self._infer_feed(params, h, impl=impl,
+                                                  tune=tune)
+            h2 = self.norm2(params["norm2"], x)
+            return x + self._infer_feed(params, h2, impl=impl, tune=tune)
 
     # -- decode ---------------------------------------------------------------
     def init_cache(self, batch, max_len, dtype=jnp.bfloat16):

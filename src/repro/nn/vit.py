@@ -133,17 +133,24 @@ class ShiftAddViT:
         + within-row reduce rather than a (B, d)·(d, k) dot: XLA CPU picks
         a different gemm/gemv strategy for tiny-M matmuls as M crosses ~1,
         which was the one op whose row values depended on the batch size.
+
+        Every op lies under a named scope (HLO `op_name` metadata only, no
+        effect on the program): `patch_embed`, each block's `mixer` and
+        `feed` (TransformerBlock.infer), and `head`.
         """
-        x = self.patch_embed(params["patch_embed"],
-                             self.patchify(images).astype(self.mc.activation_dtype))
+        with jax.named_scope("patch_embed"):
+            x = self.patch_embed(
+                params["patch_embed"],
+                self.patchify(images).astype(self.mc.activation_dtype))
         for blk, p in zip(self.blocks, params["blocks"]):
             x = blk.infer(p, x, positions=None, impl=impl, tune=tune)
-        x = self.final_norm(params["final_norm"], x)
-        pooled = jnp.mean(x, axis=1)                       # (B, d)
-        w = params["head"]["kernel"].astype(pooled.dtype)
-        logits = jnp.sum(pooled[:, :, None] * w[None], axis=1)
-        if "bias" in params["head"]:
-            logits = logits + params["head"]["bias"].astype(pooled.dtype)
+        with jax.named_scope("head"):
+            x = self.final_norm(params["final_norm"], x)
+            pooled = jnp.mean(x, axis=1)                   # (B, d)
+            w = params["head"]["kernel"].astype(pooled.dtype)
+            logits = jnp.sum(pooled[:, :, None] * w[None], axis=1)
+            if "bias" in params["head"]:
+                logits = logits + params["head"]["bias"].astype(pooled.dtype)
         return logits
 
     def loss(self, params, batch, train=True):

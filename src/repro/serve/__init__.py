@@ -9,5 +9,5 @@ from repro.serve.replicas import (DataParallelReplicas, ThreadPoolReplicas,
 from repro.serve.scheduler import Batch, MicroBatchScheduler, Part
 from repro.serve.traffic import (DEADLINE_CLASSES, SCENARIOS, Request, Trace,
                                  default_budgets, make_trace)
-from repro.serve.vision import (BucketedViTEngine, component_breakdown,
-                                policy_sweep, vit_energy_per_image)
+from repro.serve.vision import (BucketedViTEngine, policy_sweep,
+                                vit_energy_per_image)

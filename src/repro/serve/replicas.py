@@ -34,6 +34,11 @@ laptop CPU and a multi-device accelerator host.
 All submissions return `concurrent.futures.Future`s; the frontend's virtual
 clock never blocks on one until its completion event fires, so thread-pool
 replicas genuinely overlap engine execution.
+
+With the span recorder on (`serve.spans`), each batch records
+`replica.queued` (submit to worker start), `replica.run` around the engine
+call and `replica.device_wait` (blocking on the logits), all under one
+batch id.
 """
 from __future__ import annotations
 
@@ -42,6 +47,7 @@ import time
 
 import jax
 
+from repro.serve import spans
 from repro.serve.vision import DEFAULT_BUCKETS, BucketedViTEngine
 
 
@@ -93,11 +99,15 @@ class ThreadPoolReplicas(_ReplicaBase):
         if self._closed:
             raise RuntimeError("submit() on a closed ThreadPoolReplicas")
         engine = self._engine_for(slot)
+        batch = spans.ticket(len(images))
 
         def run():
-            t0 = time.perf_counter()
-            logits = jax.block_until_ready(engine.infer(images))
-            return logits, time.perf_counter() - t0
+            with spans.batch_run(batch):
+                t0 = time.perf_counter()
+                logits = engine.infer(images)
+                with spans.span("replica.device_wait"):
+                    logits = jax.block_until_ready(logits)
+                return logits, time.perf_counter() - t0
 
         return self._pool.submit(run)
 
@@ -135,9 +145,13 @@ class DataParallelReplicas(_ReplicaBase):
         """Future resolving to (logits, measured wall seconds); the sharded
         arm executes synchronously (one device set, one program at a time)."""
         fut = concurrent.futures.Future()
-        t0 = time.perf_counter()
-        logits = jax.block_until_ready(self.engines[0].infer(images))
-        fut.set_result((logits, time.perf_counter() - t0))
+        with spans.batch_run(spans.ticket(len(images))):
+            t0 = time.perf_counter()
+            logits = self.engines[0].infer(images)
+            with spans.span("replica.device_wait"):
+                logits = jax.block_until_ready(logits)
+            seconds = time.perf_counter() - t0
+        fut.set_result((logits, seconds))
         return fut
 
 

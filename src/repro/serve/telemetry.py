@@ -4,15 +4,14 @@ The latency-aware load-balancing loss (core.losses, paper §4.2 Eq. 4) and
 the static capacity split (core.moe_primitives) both consume per-expert
 latencies α_i ∝ Lat_i. Until this module those came exclusively from the
 analytic `core.energy` cost model; the serving stack, meanwhile, already
-measures real per-component and per-bucket costs (`vision.component_breakdown`,
-the BENCH_traffic service models). This closes the loop (ROADMAP item 3):
+measures real per-bucket costs (the BENCH_traffic service models). This
+closes the loop (ROADMAP item 3):
 
 - `extract_expert_telemetry` probes each MoE expert STANDALONE on the exact
   per-expert dispatch segment shapes the frozen serving path feeds it
   (`MoEPrimitives._dispatch_tokens` static views), per bucket, interleaved
   round-robin with the warmup-discarding median every calibrator uses
-  (`metrics.service_median_warm`) — `component_breakdown`'s discipline,
-  one level deeper.
+  (`metrics.service_median_warm`).
 - The result persists as a schema-versioned TELEMETRY_experts.json
   (`ExpertTelemetry.save`/`load`, same frozen-tuple + fail-open pattern as
   `kernels.autotune.TuneTable`): per-expert per-bucket wall seconds, the
@@ -151,7 +150,7 @@ def _moe_feeds(model):
 def _feed_inputs(model, run_params, images, impl=None, tune=None):
     """Yield (block, block_params, feed_input) at each block, running the
     serving forward eagerly up to every feed — the activation shapes the
-    frozen engine really dispatches (component_breakdown's probe pattern)."""
+    frozen engine really dispatches."""
     dt = model.mc.activation_dtype
     x = model.patch_embed(run_params["patch_embed"],
                           model.patchify(jnp.asarray(images)).astype(dt))

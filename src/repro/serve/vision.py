@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from repro.core import energy
 from repro.core.policy import DENSE, SHIFTADD, STAGE1
 from repro.nn.vit import ShiftAddViT, ViTConfig
+from repro.serve import spans
 from repro.serve.metrics import latency_summary
 
 # The default bucket set IS the benchmark/CI set: bench_vit.py,
@@ -173,6 +174,7 @@ class BucketedViTEngine:
             self._fwd = fwd
             self._call = jax.jit(per_shard(fwd),
                                  donate_argnums=self.donate_argnums, **jit_kw)
+            self._lower = self._call.lower
         else:
             self.plan = None
 
@@ -195,6 +197,7 @@ class BucketedViTEngine:
             fwd_j = jax.jit(per_shard(fwd, jax.sharding.PartitionSpec()),
                             **jit_kw)
             self._call = lambda images: fwd_j(self.params, images)
+            self._lower = lambda images: fwd_j.lower(self.params, images)
 
     def bucket_for(self, n: int) -> int:
         """Smallest bucket that fits n (callers chunk to max bucket first)."""
@@ -204,12 +207,20 @@ class BucketedViTEngine:
         return self.buckets[-1]
 
     def warmup(self):
-        """Compile every bucket once so serving never pays a trace."""
+        """Compile every bucket once so serving never pays a trace. With the
+        span recorder on, also note each bucket program's instruction ->
+        op_name table (`spans.note_program`, key `jit_fwd/<bucket>`): the
+        lowering and the executable come from jit's caches, so this neither
+        traces nor compiles again."""
         c = self.model.cfg
         shape = (c.image_size, c.image_size, c.in_channels)
         for b in self.buckets:
-            jax.block_until_ready(
-                self._call(jnp.zeros((b,) + shape, jnp.float32)))
+            images = jnp.zeros((b,) + shape, jnp.float32)
+            jax.block_until_ready(self._call(images))
+            if spans.enabled():
+                text = self._lower(images).compile().as_text()
+                spans.note_program(f"jit_fwd/{b}",
+                                   spans.entry_op_names(text))
         return self
 
     def infer(self, images):
@@ -220,7 +231,8 @@ class BucketedViTEngine:
         float32 warmup dtype (jit caches key on dtype — a raw uint8 client
         batch must not retrace). After warmup() this never recompiles.
         """
-        images = jnp.asarray(images, jnp.float32)
+        with spans.span("engine.put", n_images=len(images)):
+            images = jnp.asarray(images, jnp.float32)
         n = images.shape[0]
         if n == 0:
             return jnp.zeros((0, self.model.cfg.n_classes), jnp.float32)
@@ -232,17 +244,24 @@ class BucketedViTEngine:
             bucket = self.bucket_for(take)
             chunk = images[start:start + take]
             if take < bucket:
-                pad = jnp.zeros((bucket - take,) + chunk.shape[1:], chunk.dtype)
-                chunk = jnp.concatenate([chunk, pad], axis=0)
-            logits = self._call(chunk)
-            outs.append(logits[:take])
+                with spans.span("engine.pad", bucket, take):
+                    pad = jnp.zeros((bucket - take,) + chunk.shape[1:],
+                                    chunk.dtype)
+                    chunk = jnp.concatenate([chunk, pad], axis=0)
+            with spans.span("engine.enqueue", bucket, take):
+                logits = self._call(chunk)
+            with spans.span("engine.slice", bucket, take):
+                outs.append(logits[:take])
             with self._counter_lock:
                 self.batches_served += 1
                 self.padded_images_served += bucket
             start += take
         with self._counter_lock:
             self.images_served += n
-        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+        if len(outs) == 1:
+            return outs[0]
+        with spans.span("engine.slice", n_images=n):
+            return jnp.concatenate(outs, axis=0)
 
     @property
     def padding_waste(self) -> float:
@@ -300,83 +319,6 @@ def freeze_ab(base_cfg: ViTConfig = None, batch=32, iters=20, seed=0,
         "recompiles_after_warmup": sum(
             e.trace_count - 1 for e in engines.values()),
     }
-
-
-# ---------------------------------------------------------------------------
-# Measured per-component serving latency (attention / MLP-MoE / dispatch)
-# ---------------------------------------------------------------------------
-
-def component_breakdown(model: ShiftAddViT, run_params, images, iters=10):
-    """Wall-clock per-component breakdown of one serving forward.
-
-    The measured twin of benchmarks/bench_breakdown.py's roofline rows:
-    attention (norm1 + mixer serving path), MLP/MoE (norm2 + feed serving
-    path), dispatch (MoE routing + gather dispatch + combine with identity
-    experts — the pure machinery cost; a SUBSET of mlp_moe_s, not an
-    additive fourth component), and other (total minus attention and
-    mlp_moe: patchify/embed/final norm/head/residual glue). For MoE arms a
-    `dispatch_global_s` row re-measures the LEGACY flattened-co-batch
-    dispatch (group_tokens + whole-batch capacity plan) next to the served
-    per-image dispatch, and `dispatch_delta_s` = per-image − global records
-    what the batch-invariance refactor costs (or saves) on the hot path —
-    the BENCH_vit.json trajectory row ISSUE 5 asks for. Each component is jitted
-    standalone on the real activation shapes and the components are timed
-    INTERLEAVED round-robin (medians over `iters` rounds), so machine-load
-    drift hits every component equally — independently-timed components on a
-    noisy host can otherwise sum past the separately-measured total. other_s
-    is still a residual and is clamped at 0 when residual noise leaves the
-    fused total below the component sum.
-    """
-    dt = model.mc.activation_dtype
-    x0 = model.patch_embed(run_params["patch_embed"],
-                           model.patchify(jnp.asarray(images)).astype(dt))
-
-    def attn_all(x):
-        for blk, p in zip(model.blocks, run_params["blocks"]):
-            x = x + blk._infer_mixer(p, blk.norm1(p["norm1"], x), None)
-        return x
-
-    def feed_all(x):
-        for blk, p in zip(model.blocks, run_params["blocks"]):
-            x = x + blk._infer_feed(p, blk.norm2(p["norm2"], x))
-        return x
-
-    def dispatch_all(grouping):
-        from repro.core.moe_primitives import MoEPrimitives
-
-        def run(x):
-            for blk, p in zip(model.blocks, run_params["blocks"]):
-                if isinstance(blk.feed, MoEPrimitives):
-                    x = blk.feed.dispatch_only(p["feed"], x,
-                                               grouping=grouping)
-            return x
-
-        return run
-
-    has_moe = any(hasattr(blk.feed, "dispatch_only") for blk in model.blocks)
-    components = {
-        "total_s": (jax.jit(lambda im: model.infer(run_params, im)), images),
-        "attention_s": (jax.jit(attn_all), x0),
-        "mlp_moe_s": (jax.jit(feed_all), x0),
-    }
-    if has_moe:
-        components["dispatch_s"] = (jax.jit(dispatch_all("image")), x0)
-        components["dispatch_global_s"] = (jax.jit(dispatch_all("flat")), x0)
-    samples = {name: [] for name in components}
-    for name, (f, arg) in components.items():
-        jax.block_until_ready(f(arg))                    # compile
-    for _ in range(iters):
-        for name, (f, arg) in components.items():
-            t0 = time.perf_counter()
-            jax.block_until_ready(f(arg))
-            samples[name].append(time.perf_counter() - t0)
-    out = {name: sorted(ts)[len(ts) // 2] for name, ts in samples.items()}
-    out.setdefault("dispatch_s", 0.0)
-    out.setdefault("dispatch_global_s", 0.0)
-    out["dispatch_delta_s"] = out["dispatch_s"] - out["dispatch_global_s"]
-    out["other_s"] = max(out["total_s"] - out["attention_s"]
-                         - out["mlp_moe_s"], 0.0)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -465,7 +407,7 @@ def build_policy_model(base_cfg: ViTConfig, name: str,
 
 def policy_sweep(base_cfg: ViTConfig = None, batch=32, iters=10,
                  buckets=None, seed=0, policies=tuple(SWEEP_POLICIES),
-                 freeze=True, impl=None, tune=None, breakdown=False):
+                 freeze=True, impl=None, tune=None):
     """Measure every policy arm on the same pretrained dense weights.
 
     Returns the BENCH_vit.json record: per-policy batch latency (median over
@@ -555,10 +497,6 @@ def policy_sweep(base_cfg: ViTConfig = None, batch=32, iters=10,
             "compiles": engine.trace_count,
             "recompiles_after_warmup": engine.trace_count - traces_after_warmup,
         }
-        if breakdown:
-            run_params = engine.plan.params if engine.plan is not None else params
-            record["policies"][name]["breakdown"] = component_breakdown(
-                model, run_params, imgs, iters=iters)
     dense_rec = record["policies"].get("dense", {})
     dense_e = dense_rec.get("energy_pj_per_image")
     dense_lat = dense_rec.get("latency_s_per_batch")

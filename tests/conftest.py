@@ -4,3 +4,17 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _span_recorder_off():
+    """The serving path's span recorder (repro.serve.spans) is process-wide:
+    a test that turns it on, or loads a benchmark reader that does, leaves
+    it off for the next test."""
+    yield
+    mod = sys.modules.get("repro.serve.spans")
+    if mod is not None:
+        mod.disable()
