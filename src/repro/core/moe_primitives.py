@@ -274,10 +274,15 @@ class MoEPrimitives:
     def _gates(select_logits, clean_logits):
         """THE gating rule, single home for train and serving: top-1 on
         `select_logits` (noisy while training, clean at inference), gate from
-        the clean softmax. Returns (probs (G,S,E), top1 (G,S), gate (G,S,1))."""
+        the clean softmax. Returns (probs (G,S,E), top1 (G,S), gate (G,S,1)).
+
+        The gate is a select over the experts, not a gather: one term of the
+        sum is nonzero, so it equals probs[top1] exactly, with the same
+        gradient (see `nn.dispatch` on why a gather is avoided)."""
         probs = jax.nn.softmax(clean_logits, axis=-1)
         top1 = jnp.argmax(select_logits, axis=-1)
-        gate = jnp.take_along_axis(probs, top1[..., None], axis=-1)
+        hit = top1[..., None] == jnp.arange(probs.shape[-1])
+        gate = jnp.sum(jnp.where(hit, probs, 0.0), axis=-1, keepdims=True)
         return probs, top1, gate
 
     def _route_dispatch(self, params, xg, select_logits, clean_logits, stats):

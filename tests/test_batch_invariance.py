@@ -223,10 +223,13 @@ def _identity_segments(buf, caps):
     return outs
 
 
-def _check_dispatch_vs_oracle(b, s, e, caps, seed):
+def _check_dispatch_vs_oracle(b, s, e, caps, seed, all_to=None):
+    """`all_to` routes every token to that expert instead of at random."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     x = jax.random.normal(ks[0], (b, s, 4))
     idx = jax.random.randint(ks[1], (b, s), 0, e)
+    if all_to is not None:
+        idx = jnp.full_like(idx, all_to)
     gate = jax.nn.softmax(jax.random.normal(ks[2], (b, s, e)), -1)[..., 0]
     buf, info = dispatch_infer(x, idx, gate, caps)
     y = combine_infer(_identity_segments(buf, caps), info)
@@ -263,8 +266,11 @@ def test_per_image_dispatch_matches_numpy_oracle_examples():
             (4, 16, 2, [10, 11]),        # the cf-1.25 serving split shape
             (3, 12, 3, [2, 3, 5]),       # heterogeneous capacities
             (2, 10, 2, [1, 10]),         # starved expert 0
+            (3, 6, 2, [8, 9]),           # capacities past the row length
     ]):
         _check_dispatch_vs_oracle(b, s, e, caps, seed)
+    # Every token to the last expert, past its capacity.
+    _check_dispatch_vs_oracle(2, 10, 3, [2, 3, 4], 5, all_to=2)
 
 
 @pytest.mark.slow

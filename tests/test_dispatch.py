@@ -1,11 +1,14 @@
 """Property tests for the grouped capacity dispatcher (nn/dispatch.py) —
 the component both MoE flavors (and their TPU sharding) rest on."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _propshim import given, settings, st  # optional-hypothesis shim
 
+from repro.core.moe_primitives import MoEPrimitives
 from repro.nn.dispatch import choose_groups, combine, dispatch
 
 
@@ -172,14 +175,17 @@ def _identity_expert_outs(buf, caps):
     return outs
 
 
-def _check_infer_matches_scatter(g, s, e, caps, seed):
+def _check_infer_matches_scatter(g, s, e, caps, seed, all_to=None):
     """combine_infer(dispatch_infer(x)) with identity experts must equal the
     training scatter path bit-for-bit (same token-order priority, same
-    drops) — the gather rewrite may not change a single logit."""
+    drops) — the gather rewrite may not change a single logit. `all_to`
+    routes every token to that expert instead of at random."""
     from repro.nn.dispatch import combine_infer, dispatch_infer
 
     d = 4
     xg, idx, gate = _route(g, s, d, e, 1, seed)
+    if all_to is not None:
+        idx = jnp.full_like(idx, all_to)
     buf_t, aux_t = dispatch(xg, idx, gate, caps, stats=False)
     y_t = combine(buf_t, aux_t, s, d)
     buf_i, info = dispatch_infer(xg, idx[..., 0], gate[..., 0], caps)
@@ -205,8 +211,11 @@ def test_infer_dispatch_matches_scatter_examples():
             (2, 16, 2, [16, 16]),       # no drops possible
             (1, 10, 3, [2, 3, 5]),      # heterogeneous capacities
             (3, 12, 2, [1, 12]),        # starved expert 0
+            (2, 6, 2, [9, 8]),          # capacities past the row length
     ]):
         _check_infer_matches_scatter(g, s, e, caps, seed)
+    # Every token to the last expert: the others' segments hold no live row.
+    _check_infer_matches_scatter(2, 10, 3, [2, 3, 4], 7, all_to=2)
 
 
 def test_infer_dispatch_all_tokens_one_expert():
@@ -244,3 +253,49 @@ def test_stats_false_skips_bookkeeping_but_combines_identically():
     np.testing.assert_array_equal(np.asarray(buf_t), np.asarray(buf_i))
     np.testing.assert_array_equal(np.asarray(combine(buf_t, aux_t, s, d)),
                                   np.asarray(combine(buf_i, aux_i, s, d)))
+
+
+# ---------------------------------------------------------------------------
+# Gating rule and the lowered serving MoE
+# ---------------------------------------------------------------------------
+
+def test_gates_match_numpy_oracle():
+    """`_gates` gives probs[top1] bit for bit, with top1 the argmax of the
+    selecting logits: clean ones (serving) and noisy ones whose argmax
+    differs from the clean one (training); its gradient is that of the
+    indexed lookup."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    clean = jax.random.normal(ks[0], (3, 64, 3))
+    noisy = clean + 2.0 * jax.random.normal(ks[1], clean.shape)
+    assert (np.argmax(noisy, -1) != np.argmax(clean, -1)).any()
+    for select in (clean, noisy):
+        probs, top1, gate = MoEPrimitives._gates(select, clean)
+        want_top1 = np.argmax(np.asarray(select), axis=-1)
+        np.testing.assert_array_equal(np.asarray(top1), want_top1)
+        want = np.take_along_axis(np.asarray(probs), want_top1[..., None], -1)
+        np.testing.assert_array_equal(np.asarray(gate), want)
+
+        def indexed(c, select=select):
+            p = jax.nn.softmax(c, axis=-1)
+            t = jnp.argmax(select, axis=-1)
+            return jnp.sum(jnp.take_along_axis(p, t[..., None], -1) ** 2)
+
+        got = jax.grad(lambda c, select=select: jnp.sum(
+            MoEPrimitives._gates(select, c)[2] ** 2))(clean)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(jax.grad(indexed)(clean)))
+
+
+def test_serving_moe_lowers_without_index_gathers():
+    """The compiled serving MoE moves rows with 1 + n_experts gathers (the
+    flat dispatch gather, one combine gather per expert) and computes its
+    index math (gate, rank, source rows) with none: a batched gather of
+    scalars runs as a serial loop on TPU."""
+    moe = MoEPrimitives(64, 128, ("mult", "shift"), capacity_ref_tokens=196)
+    params = moe.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, 196, 64))
+    hlo = jax.jit(moe.infer).lower(params, x).compile().as_text()
+    gathers = [ln for ln in hlo.splitlines()
+               if re.search(r"= \S+ gather\(", ln)]
+    assert len(gathers) == 1 + moe.n_experts, gathers
+    assert not [ln for ln in gathers if "take_along_axis" in ln]
