@@ -16,6 +16,7 @@ class Context:
     peaks: dict               # the device's row of peaks.json
     images_per_s: float       # images completed per second before the
                               # profiler started (the untraced part)
+    family: object = None     # spec.Family of the configuration
 
 
 def kernel_roofline(ctx: Context, pattern: str, calls_fn, cost_fn):

@@ -1,22 +1,45 @@
 """Finds a cell's pieces by name: `BENCHMARK.json`, the configuration file it
+names, the model family `bench/families/<family>/` that the configuration
 names, the traffic file `bench/traffic/<traffic>.json`, the per-layer metric
 readers `bench/metrics/<metric>.py` and the peak table `bench/peaks.json`.
 
-Nothing here knows a cell, a traffic mix or a metric by name: a later change
-adds one by adding its file and its entry in `BENCHMARK.json`.
+Nothing here knows a cell, a model family, a traffic mix or a metric by
+name: a later change adds one by adding its files and its entries in
+`BENCHMARK.json`.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
 from pathlib import Path
 
 BENCH_DIR = "bench"
+# The files of a model family and what each has to define: make(cfg, seed)
+# -> weights; build(cfg, weights) -> (model, params); forward(weights,
+# images, cfg, dtype) -> logits and the keys of cfg it reads; the counts.
+FAMILY_FILES = {
+    "weights": ("make",),
+    "program": ("build",),
+    "reference": ("forward", "cache_keys"),
+    "cost": ("ops_per_image", "kernel_calls"),
+}
 
 
 class SpecError(Exception):
-    """A cell, configuration, traffic mix, metric or device kind that the
-    benchmark's files do not define."""
+    """A cell, configuration, model family, traffic mix, metric or device
+    kind that the benchmark's files do not define, or a configuration that
+    the program does not run as it states."""
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """The modules of `bench/families/<name>/`."""
+    name: str
+    weights: object
+    program: object
+    reference: object
+    cost: object
 
 
 def load_json(path: Path) -> dict:
@@ -50,18 +73,42 @@ def load_traffic(root: Path, name: str) -> dict:
     return load_json(path)
 
 
+def _load_module(path: Path, module_name: str, names: tuple):
+    """The Python file `path`, which has to define each of `names`."""
+    if not path.is_file():
+        raise SpecError(f"no file {path}")
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    for name in names:
+        if not hasattr(module, name):
+            raise SpecError(f"{path} defines no {name}")
+    return module
+
+
 def load_metric_reader(root: Path, name: str):
     """The module `bench/metrics/<name>.py`; its `read(ctx)` returns the
     metric's value, or None where the run has nothing to read."""
     path = Path(root) / BENCH_DIR / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise SpecError(f"no metric reader {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
+    module = _load_module(path, f"bench_metric_{name}", ())
     if not callable(getattr(module, "read", None)):
         raise SpecError(f"{path} defines no read(ctx)")
     return module
+
+
+def load_family(root: Path, cfg: dict) -> Family:
+    """The model family that configuration `cfg` names by its key `family`:
+    `weights.py`, `program.py`, `reference.py` and `cost.py` under
+    `bench/families/<family>/`."""
+    name = cfg.get("family")
+    path = Path(root) / BENCH_DIR / "families" / str(name)
+    if name is None or not path.is_dir():
+        raise SpecError(f"configuration {cfg.get('name')!r} names the model "
+                        f"family {name!r}, and there is no directory {path}")
+    return Family(name, **{
+        file: _load_module(path / f"{file}.py", f"bench_family_{name}_{file}",
+                           names)
+        for file, names in FAMILY_FILES.items()})
 
 
 def load_peaks(root: Path, device_kind: str) -> dict:

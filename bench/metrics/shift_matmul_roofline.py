@@ -1,6 +1,7 @@
 """Kernel `kernels/shift_matmul.py`: roofline share of its device time in
-the traced window. Its calls are the HLO instructions named after the
-jitted `shift_matmul_pallas`."""
+the traced window. Its calls per forward are the family's
+`cost.kernel_calls`; its device events are the HLO instructions named after
+the jitted `shift_matmul_pallas`."""
 from bench.lib import cost
 from bench.lib.context import kernel_roofline
 
@@ -8,5 +9,6 @@ PATTERN = r"^shift_matmul_pallas(\.\d+)?$"
 
 
 def read(ctx):
-    return kernel_roofline(ctx, PATTERN, cost.shift_matmul_calls,
-                           cost.shift_matmul_cost)
+    def calls(cfg, batch):
+        return ctx.family.cost.kernel_calls(cfg, batch)["shift_matmul"]
+    return kernel_roofline(ctx, PATTERN, calls, cost.shift_matmul_cost)

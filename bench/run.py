@@ -2,14 +2,17 @@
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell (`BENCHMARK.json`) names a configuration file and a traffic file.
-Set-up makes the weights from the seed, builds the program's frozen serving
-path (`build_policy_model` -> `make_replicas(arm="thread")` over
-`BucketedViTEngine(impl="pallas")`), warms every shape the traffic will use
-and calibrates the scheduler. The window then drives the program's
+The cell (`BENCHMARK.json`) names a configuration file and a traffic file;
+the configuration names its model family, `bench/families/<family>/`. From
+the family, set-up makes the weights from the seed (`weights.make`) and the
+program's model and parameters (`program.build`); it then builds the
+program's serving path over them (`make_replicas(arm="thread")`, frozen
+bucket programs with `impl="pallas"`), warms every shape the traffic will
+use and calibrates the scheduler. The window then drives the program's
 `MicroBatchScheduler` and replicas on the wall clock for `--seconds`
-(`bench/lib/serve_loop.py`). After it, the program is freed and the plain
-reference (`bench/lib/reference.py`) recomputes the checked requests.
+(`bench/lib/serve_loop.py`). After it, the program is freed and the
+family's plain reference (`reference.forward`, through
+`bench/lib/reference.py`) recomputes the checked requests.
 
 `--trace 0` reports the cell's end-to-end metrics, `--trace 1` its
 per-layer metrics, read from a device trace of the window's last seconds,
@@ -98,38 +101,15 @@ class CompileCounter:
         return dict(self.counts)
 
 
-def build_program(cfg, weights, traffic, impl):
+def build_program(family, cfg, weights, traffic, impl):
     """The program's serving path for this configuration and traffic."""
-    import dataclasses
-
-    from repro.core.policy import DENSE
-    from repro.nn.vit import ShiftAddViT, ViTConfig
     from repro.serve.replicas import make_replicas
-    from repro.serve.vision import build_policy_model
 
-    vcfg = ViTConfig(image_size=cfg["image_size"], patch_size=cfg["patch_size"],
-                     in_channels=cfg["in_channels"], n_classes=cfg["n_classes"],
-                     n_layers=cfg["n_layers"], d_model=cfg["d_model"],
-                     n_heads=cfg["n_heads"], d_ff=cfg["d_ff"],
-                     dtype=cfg["activation_dtype"],
-                     moe_capacity=cfg.get("moe_capacity_factor", 1.25))
-    dense_model = ShiftAddViT(dataclasses.replace(vcfg, policy=DENSE))
-    model, params = build_policy_model(vcfg, cfg["policy"], dense_model,
-                                       weights["dense"])
-    if cfg["policy"] == "shiftadd":
-        for i, blk in enumerate(params["blocks"]):
-            blk["feed"]["router"] = {"kernel": weights["router"][i]}
-            blk["mixer"]["dwconv"] = weights["dwconv"][i]
-        caps = model.blocks[0].feed.capacity_plan(vcfg.n_patches)[0]
-        if list(caps) != list(cfg["moe_capacity_per_image"]):
-            raise BenchError(f"the program plans MoE capacities {caps} per "
-                             f"image; the configuration states "
-                             f"{cfg['moe_capacity_per_image']}")
+    model, params = family.program.build(cfg, weights)
     serve = traffic["serve"]
-    replicas = make_replicas(model, params, n_replicas=serve["replicas"],
-                             arm="thread", buckets=tuple(serve["buckets"]),
-                             impl=impl)
-    return replicas
+    return make_replicas(model, params, n_replicas=serve["replicas"],
+                         arm="thread", buckets=tuple(serve["buckets"]),
+                         impl=impl)
 
 
 def warm(replicas, cfg, traffic):
@@ -218,13 +198,13 @@ def run_cell(root, cell_name, seed, seconds, trace, *, impl="pallas",
     from bench.lib.context import Context
     from bench.lib.serve_loop import run_window
     from bench.lib.traffic import ClosedClients, payload_indices, payload_pool
-    from bench.lib.weights import make_weights
     from repro.serve.scheduler import MicroBatchScheduler
 
     t_process = T_PROCESS if t_process is None else t_process
     bench = spec.load_benchmark(root)
     cell = spec.find_cell(bench, cell_name)
     cfg = spec.load_config(root, bench, cell["config"])
+    family = spec.load_family(root, cfg)
     traffic = spec.load_traffic(root, cell["traffic"])
     kind = "per_layer" if trace else "end_to_end"
     wanted = spec.cell_metrics(bench, cell_name, kind)
@@ -234,8 +214,8 @@ def run_cell(root, cell_name, seed, seconds, trace, *, impl="pallas",
                for m in wanted} if trace else {}
 
     compiles = CompileCounter()
-    weights = make_weights(cfg, seed)
-    replicas = build_program(cfg, weights, traffic, impl)
+    weights = family.weights.make(cfg, seed)
+    replicas = build_program(family, cfg, weights, traffic, impl)
     del weights
     engine = replicas.engines[0]
     service = warm(replicas, cfg, traffic)
@@ -293,15 +273,16 @@ def run_cell(root, cell_name, seed, seconds, trace, *, impl="pallas",
     gc.collect()
 
     t_ref = time.perf_counter()
-    weights = make_weights(cfg, seed)
-    refs = {p: reference.logits(weights, images, cfg, precision=p)
+    weights = family.weights.make(cfg, seed)
+    refs = {p: reference.logits(family, weights, images, cfg, precision=p)
             for p in ("highest", "default")}
     numbers = check.compare(served, refs, cfg["n_classes"])
     correct, checks = check.judge(numbers, cfg["limits"])
     ref_s = time.perf_counter() - t_ref
     log("numbers: " + " ".join(f"{k} {v:.6g}" for k, v in numbers.items()))
     if with_control:
-        ctl = reference.logits(weights, images, cfg, dtype="bfloat16")
+        ctl = reference.logits(family, weights, images, cfg,
+                               dtype="bfloat16")
         control_numbers = check.compare(list(ctl), refs, cfg["n_classes"])
         control_correct = check.judge(control_numbers, cfg["limits"])[0]
 
@@ -314,7 +295,8 @@ def run_cell(root, cell_name, seed, seconds, trace, *, impl="pallas",
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = Context(cfg=cfg, window=window, trace=tr, peaks=peaks,
-                      images_per_s=window.images_per_s_until(tracer.t_on))
+                      images_per_s=window.images_per_s_until(tracer.t_on),
+                      family=family)
         for m in wanted:
             value = readers[m["name"]].read(ctx)
             if value is None:
@@ -381,7 +363,7 @@ def main(argv=None):
     try:
         result = run_cell(ROOT, args.workload, args.seed, args.seconds,
                           bool(args.trace))
-    except BenchError as e:
+    except (BenchError, spec.SpecError) as e:
         log(f"bench: {e}")
         return 1
     print(json.dumps(result), flush=True)

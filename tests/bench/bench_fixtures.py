@@ -27,15 +27,18 @@ CLOSED = dict(loop="closed", clients=4, sizes={"kind": "fixed", "value": 8},
 
 def make_root(path: Path) -> Path:
     """A checkout root with BENCHMARK.json, two tiny configurations, a
-    closed-loop traffic mix, and the repository's metric readers."""
+    closed-loop traffic mix, and the repository's model families and metric
+    readers."""
     (path / "bench" / "configs").mkdir(parents=True)
     (path / "bench" / "traffic").mkdir(parents=True)
-    shutil.copytree(REPO / "bench" / "metrics", path / "bench" / "metrics")
+    for part in ("families", "metrics"):
+        shutil.copytree(REPO / "bench" / part, path / "bench" / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(REPO / "bench" / "peaks.json", path / "bench" / "peaks.json")
     configs, cells = [], []
     for arm, stage in (("shiftadd", 2), ("dense", 0)):
-        cfg = dict(TINY, name=f"tiny-{arm}", policy=arm, convert_stage=stage,
-                   limits=TINY_LIMITS)
+        cfg = dict(TINY, name=f"tiny-{arm}", family="vit", policy=arm,
+                   convert_stage=stage, limits=TINY_LIMITS)
         file = f"bench/configs/tiny-{arm}.json"
         (path / file).write_text(json.dumps(cfg))
         configs.append({"name": cfg["name"], "file": file})

@@ -3,8 +3,10 @@ import json
 
 import pytest
 
-from bench.lib import cost
+from bench.lib import cost, spec
 from bench_fixtures import REPO
+
+VIT = spec.load_family(REPO, {"family": "vit"})
 
 
 def config(arm):
@@ -19,7 +21,7 @@ def test_dense_deit_tiny_ops_per_image():
             + 12 * (4 * 196 * 192 * 192 + 2 * 3 * 196 * 196 * 64
                     + 2 * 196 * 192 * 768)
             + 192 * 1000)
-    assert cost.vit_ops_per_image(config("dense")) == pytest.approx(2 * macs)
+    assert VIT.cost.ops_per_image(config("dense")) == pytest.approx(2 * macs)
     assert 2.48e9 < 2 * macs < 2.50e9
 
 
@@ -31,7 +33,7 @@ def test_shiftadd_deit_tiny_ops_per_image():
             + 12 * (4 * 196 * 192 * 192 + 2 * 3 * 196 * 64 * 64
                     + 196 * 192 * 3 + 196 * 192 * 2 + 2 * 196 * 192 * 768)
             + 192 * 1000)
-    assert cost.vit_ops_per_image(config("shiftadd")) == pytest.approx(2 * macs)
+    assert VIT.cost.ops_per_image(config("shiftadd")) == pytest.approx(2 * macs)
     assert 2.24e9 < 2 * macs < 2.27e9
 
 
@@ -49,11 +51,12 @@ def test_bidir_attention_cost_by_hand():
 
 def test_calls_of_one_shiftadd_forward():
     cfg = config("shiftadd")
-    calls = cost.shift_matmul_calls(cfg, 32)
-    assert len(calls) == 12 * 6
-    assert calls[:6] == [(6272, 192, 192)] * 4 + [(32 * 138, 192, 768),
+    calls = VIT.cost.kernel_calls(cfg, 32)
+    shift = calls["shift_matmul"]
+    assert len(shift) == 12 * 6
+    assert shift[:6] == [(6272, 192, 192)] * 4 + [(32 * 138, 192, 768),
                                                   (32 * 138, 768, 192)]
-    assert cost.bidir_attn_calls(cfg, 32) == [(96, 196, 64, 64)] * 12
+    assert calls["bidir_attn"] == [(96, 196, 64, 64)] * 12
 
 
 def test_roofline_is_the_larger_bound():

@@ -14,9 +14,10 @@ import numpy as np
 import pytest
 
 from bench import run
-from bench.lib import reference
-from bench.lib.weights import make_weights
+from bench.lib import reference, spec
 from bench_fixtures import REPO, TINY
+
+VIT = spec.load_family(REPO, {"family": "vit"})
 
 
 def run_tiny(root, cell, impl="xla", **kw):
@@ -91,7 +92,7 @@ def test_reference_matches_the_program_on_the_cpu(policy):
     from repro.serve.vision import build_policy_model
 
     cfg = dict(TINY, policy=policy)
-    w = make_weights(cfg, 5)
+    w = VIT.weights.make(cfg, 5)
     vcfg = ViTConfig(**{k: TINY[k] for k in (
         "image_size", "patch_size", "in_channels", "n_classes", "n_layers",
         "d_model", "n_heads", "d_ff")})
@@ -105,7 +106,7 @@ def test_reference_matches_the_program_on_the_cpu(policy):
                                                dtype=np.uint8)
     got = np.asarray(model.infer(params, jnp.asarray(images, jnp.float32),
                                  impl="xla"))
-    want = reference.logits(w, images, cfg)
+    want = reference.logits(VIT, w, images, cfg)
     assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
 
 
@@ -118,7 +119,7 @@ def test_weights_have_the_programs_dense_layout():
         "image_size", "patch_size", "in_channels", "n_classes", "n_layers",
         "d_model", "n_heads", "d_ff")}, policy=DENSE)
     prog = jax.eval_shape(ShiftAddViT(vcfg).init, jax.random.PRNGKey(0))
-    ours = jax.eval_shape(lambda: make_weights(cfg, 0))["dense"]
+    ours = jax.eval_shape(lambda: VIT.weights.make(cfg, 0))["dense"]
     assert (jax.tree_util.tree_structure(prog)
             == jax.tree_util.tree_structure(ours))
     assert jax.tree_util.tree_all(jax.tree_util.tree_map(

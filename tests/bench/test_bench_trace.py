@@ -9,6 +9,7 @@ from bench.lib.trace import Event, Trace
 from bench_fixtures import REPO
 
 DEV = "/device:TPU:0"
+VIT = spec.load_family(REPO, {"family": "vit"})
 
 
 def small_trace():
@@ -42,7 +43,7 @@ def test_kernel_roofline_counts_calls_per_forward():
     cfg = {"image_size": 224, "patch_size": 16, "d_model": 192, "d_ff": 768,
            "n_layers": 1, "moe_experts": ["mult", "shift"],
            "moe_capacity_per_image": [108, 138]}
-    calls = cost.shift_matmul_calls(cfg, 32)
+    calls = VIT.cost.shift_matmul_calls(cfg, 32)
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     roof = sum(cost.roofline_s(*cost.shift_matmul_cost(*c), 197e12, 819e9)
                for c in calls)
@@ -55,9 +56,10 @@ def test_kernel_roofline_counts_calls_per_forward():
     ctx = Context(cfg=cfg, window=Window(), trace=tr, peaks=peaks,
                   images_per_s=1.0)
     share = kernel_roofline(ctx, r"^shift_matmul_pallas(\.\d+)?$",
-                            cost.shift_matmul_calls, cost.shift_matmul_cost)
+                            VIT.cost.shift_matmul_calls, cost.shift_matmul_cost)
     assert share == pytest.approx(25.0)
-    assert kernel_roofline(ctx, r"^no_such_kernel$", cost.shift_matmul_calls,
+    assert kernel_roofline(ctx, r"^no_such_kernel$",
+                           VIT.cost.shift_matmul_calls,
                            cost.shift_matmul_cost) is None
 
 
@@ -97,10 +99,11 @@ def test_load_names_device_ops_by_their_hlo_instruction():
 def test_kernel_events_of_one_forward_are_its_calls():
     cfg = json.loads((REPO / "bench/configs/deit-tiny-shiftadd.json").read_text())
     tr = chip_trace()
-    for metric, calls in (("shift_matmul_roofline", cost.shift_matmul_calls),
-                          ("bidir_attn_roofline", cost.bidir_attn_calls)):
+    calls = VIT.cost.kernel_calls(cfg, 32)
+    for metric, kernel in (("shift_matmul_roofline", "shift_matmul"),
+                           ("bidir_attn_roofline", "bidir_attn")):
         reader = spec.load_metric_reader(REPO, metric)
-        assert len(tr.kernel_events(reader.PATTERN)) == len(calls(cfg, 32))
+        assert len(tr.kernel_events(reader.PATTERN)) == len(calls[kernel])
 
 
 def test_readers_on_a_chip_trace_read_shares_below_the_whole():
@@ -109,7 +112,8 @@ def test_readers_on_a_chip_trace_read_shares_below_the_whole():
     class Window:
         batches = [(32, 32, "full", 0.0, 0.1)]
     ctx = Context(cfg=cfg, window=Window(), trace=chip_trace(),
-                  peaks=spec.load_peaks(REPO, "TPU v5 lite"), images_per_s=1.0)
+                  peaks=spec.load_peaks(REPO, "TPU v5 lite"), images_per_s=1.0,
+                  family=spec.load_family(REPO, cfg))
     for metric in ("shift_matmul_roofline", "bidir_attn_roofline",
                    "idle_share.bulk"):
         value = spec.load_metric_reader(REPO, metric).read(ctx)
