@@ -170,20 +170,27 @@ def linear_attention_cell(bucket, g, n, dk, dv, *, blocks=None):
                    PEAK_FLOPS_BF16)
 
 
-def bidir_attention_cell(bucket, g, n, dk, dv):
-    """Fused bidirectional kernel: whole sequence per grid step in VMEM."""
+def bidir_attention_cell(bucket, g, n, dk, dv, n_kv=None):
+    """Fused bidirectional kernel: whole sequence per grid step in VMEM.
+    q and out hold n rows, k and v n_kv (default n: self-attention; fewer
+    under PVT's spatial-reduction attention)."""
     from repro.kernels import bidir_linear_attention as _bidir
 
+    n_kv = n if n_kv is None else n_kv
     dkp, dvp = _ceil_to(dk, 128), _ceil_to(dv, 128)
-    np_ = _ceil_to(n, 8)
-    vmem = 2 * (2 * np_ * dkp * F32 + 2 * np_ * dvp * F32)   # q, k | v, out
-    over_cap = np_ > _bidir.MAX_FUSED_N
+    np_, nkp = _ceil_to(n, 8), _ceil_to(n_kv, 8)
+    rows = (np_ + nkp) * dkp + (nkp + np_) * dvp     # q, k | v, out
+    vmem = 2 * rows * F32
+    over_cap = max(np_, nkp) > _bidir.MAX_FUSED_N
+    geometry = {"g": g, "n": n, "dk": dk, "dv": dv}
+    if n_kv != n:
+        geometry["n_kv"] = n_kv
     cell = _finish("bidir_linear_attention", "encoder_attn", bucket,
-                   {"g": g, "n": n, "dk": dk, "dv": dv},
-                   {"n_block": np_},
+                   geometry, {"n_block": np_},
                    (g,), {"n": np_, "dk": dkp, "dv": dvp},
-                   vmem, 4.0 * g * np_ * dkp * dvp, 4.0 * g * n * dk * dv,
-                   g * (2 * np_ * dkp + 2 * np_ * dvp) * F32,
+                   vmem, 2.0 * g * (np_ + nkp) * dkp * dvp,
+                   2.0 * g * (n + n_kv) * dk * dv,
+                   g * rows * F32,
                    PEAK_FLOPS_BF16)
     if over_cap:   # the kernel refuses these shapes outright
         cell.classification = "vmem_overflow"
@@ -247,7 +254,8 @@ def cell_for_site(site_spec: dict, bucket: int, blocks=None) -> Cell:
         return linear_attention_cell(bucket, s["g"], s["n"], s["dk"],
                                      s["dv"], blocks=blocks)
     assert kernel == "bidir_linear_attention", kernel
-    return bidir_attention_cell(bucket, s["g"], s["n"], s["dk"], s["dv"])
+    return bidir_attention_cell(bucket, s["g"], s["n"], s["dk"], s["dv"],
+                                s.get("n_kv"))
 
 
 def cells_for_bucket(cfg, b) -> list:

@@ -51,7 +51,8 @@ def _featurize(q, k, feature="binary"):
 def binary_linear_attention(q, k, v, *, causal=False, chunk=128, train=True,
                             feature="binary", return_state=False,
                             lengths=None):
-    """q, k: (B, H, N, Dk); v: (B, H, N, Dv) → (B, H, N, Dv).
+    """q, k: (B, H, N, Dk); v: (B, H, N, Dv) → (B, H, N, Dv). Non-causal, k
+    and v may hold fewer tokens than q.
 
     With return_state=True (causal only) also returns the final recurrent
     carry {"kv", "ksum", "vsum", "count"} in the init_decode_state layout —
@@ -75,7 +76,7 @@ def binary_linear_attention(q, k, v, *, causal=False, chunk=128, train=True,
 
 
 def _bidirectional(q, k, v, train=True, feature="binary"):
-    n = q.shape[-2]
+    n = k.shape[-2]            # k/v may be a reduced token set (PVT's SRA)
     bq, bk, d = _featurize(q, k, feature)
     kv = jnp.einsum("bhnd,bhne->bhde", bk, v)           # MatAdd (±1 operand)
     ksum = jnp.sum(bk, axis=-2)                          # (B,H,Dk)

@@ -21,9 +21,10 @@ materializes a `DeployPlan`:
 - **MoE capacities/offsets** are precomputed into each
   `MoEPrimitives.capacity_plan` memo for the PER-IMAGE token counts the
   serving dispatch routes over (one routing group per batch row — ISSUE 5).
-  Tokens-per-image is a property of the model geometry, not of the bucket,
-  so one warmed count covers every bucket and the plan is identical for an
-  image no matter which co-batch it arrives in.
+  Tokens-per-image is a property of the model geometry, not of the bucket
+  (one count for a ViT, one per stage for PVTv2), so the warmed counts
+  cover every bucket and the plan is identical for an image no matter
+  which co-batch it arrives in.
 
 The plan's `params` tree is what the serving engine's jitted forward closes
 over; `ShiftLinear.__call__` recognizes the frozen leaves, so `infer` paths
@@ -79,8 +80,8 @@ class DeployPlan:
     moe_layers: how many MoE feeds had capacity plans warmed.
     token_counts: PER-IMAGE token counts the capacity plans were warmed for
       (the serving dispatch routes one group per batch row, so these are
-      tokens-per-image — e.g. `cfg.n_patches` for the ViT engine — not
-      flattened co-batch group sizes).
+      tokens-per-image — the model's `token_counts`, e.g. `(n_patches,)`
+      for the ViT — not flattened co-batch group sizes).
     tune: optional kernels.autotune.TuneTable the frozen program's kernel
       calls consume (threaded by the engine to every infer; hashable, so the
       jit cache keys on it). None → module-default block caps.
@@ -118,11 +119,12 @@ def prepare_inference(model, params, impl=None, token_counts=(),
     """Build the DeployPlan for `model` + `params` (ISSUE 3 tentpole entry).
 
     model: anything with an optional `blocks` list whose block feeds may be
-      `MoEPrimitives` (ShiftAddViT, TransformerBlock stacks, ...). Only the
-      param tree is required; the model is consulted to warm MoE capacity
-      plans for `token_counts` (PER-IMAGE token counts — the serving
-      dispatch plans capacity per batch row) so dispatch trace time pays no
-      capacity math either.
+      `MoEPrimitives` (ShiftAddViT, PVTv2, TransformerBlock stacks, ...).
+      Only the param tree is required; the model is consulted to warm MoE
+      capacity plans for `token_counts` (PER-IMAGE token counts — the
+      serving dispatch plans capacity per batch row) so dispatch trace time
+      pays no capacity math either. Every MoE feed is warmed at every
+      count: a pyramid's stages each route one of them.
     """
     from repro.core.moe_primitives import MoEPrimitives
     from repro.kernels import ops

@@ -331,7 +331,7 @@ class MoEPrimitives:
     # Serving threads kernel impl/tune through to the shift experts.
     accepts_impl = True
 
-    def infer(self, params, x, impl=None, tune=None):
+    def infer(self, params, x, impl=None, tune=None, mid=None):
         """Deterministic inference dispatch — the serving fast path.
 
         Routes on clean-logit argmax (no router noise, no rng) with static
@@ -348,22 +348,60 @@ class MoEPrimitives:
         (no token ever competes with another image's tokens for capacity).
         Returns y only.
 
+        mid: a function of the hidden activations on the whole token grid,
+        (..., S, d_hidden) → same shape, run between each expert's first and
+        second linear (PVT's Mix-FFN: a depthwise conv needs every
+        neighbour, so it cannot run inside a routed expert). Each expert's
+        first linear runs on its capacity rows; the rows come back to the
+        token grid (tokens over capacity give zero there); `mid` runs once;
+        the same routing plan sends the rows back to the same experts for
+        their second linear; the gate scales the result. The experts have
+        to be two-linear MLPs (`up`, `down`), whose own activation `mid`
+        replaces. With mid=None the experts run whole.
+
         Named scopes: `moe_dispatch` (router and dispatch), one
         `expert_<kind>` per expert, `moe_combine`.
         """
-        from repro.nn.dispatch import combine_infer
+        from repro.nn.dispatch import combine_infer, redispatch_infer
 
         with jax.named_scope("moe_dispatch"):
             _, info, segments, ungroup = self._dispatch_tokens(params, x)
-        outs = []
-        for i, (expert, seg) in enumerate(zip(self.experts, segments)):
-            with jax.named_scope(f"expert_{self.expert_kinds[i]}"):
-                outs.append(
-                    expert(params["experts"][i], seg, impl=impl, tune=tune)
-                    if getattr(expert, "accepts_impl", False)
-                    else expert(params["experts"][i], seg))
+        if mid is None:
+            outs = []
+            for i, (expert, seg) in enumerate(zip(self.experts, segments)):
+                with jax.named_scope(f"expert_{self.expert_kinds[i]}"):
+                    outs.append(
+                        expert(params["experts"][i], seg, impl=impl,
+                               tune=tune)
+                        if getattr(expert, "accepts_impl", False)
+                        else expert(params["experts"][i], seg))
+            with jax.named_scope("moe_combine"):
+                return ungroup(combine_infer(outs, info)).astype(x.dtype)
+
+        hidden = self._expert_linears("up", params, segments, impl, tune)
+        with jax.named_scope("moe_combine"):
+            h = combine_infer(hidden, info, gated=False)
+        h = mid(h.reshape(*x.shape[:-1], h.shape[-1]))
+        with jax.named_scope("moe_dispatch"):
+            g, s = info["expert"].shape
+            buf = redispatch_infer(h.reshape(g, s, -1), info)
+            _, offsets = self.capacity_plan(s)
+            segments = [buf[:, off:off + cap, :]
+                        for off, cap in zip(offsets, info["caps"])]
+        outs = self._expert_linears("down", params, segments, impl, tune)
         with jax.named_scope("moe_combine"):
             return ungroup(combine_infer(outs, info)).astype(x.dtype)
+
+    def _expert_linears(self, name, params, segments, impl, tune):
+        """One linear (`up` or `down`) of every expert on its segment."""
+        outs = []
+        for i, (expert, seg) in enumerate(zip(self.experts, segments)):
+            lin, p = getattr(expert, name), params["experts"][i][name]
+            with jax.named_scope(f"expert_{self.expert_kinds[i]}"):
+                outs.append(lin(p, seg, impl=impl, tune=tune)
+                            if getattr(lin, "accepts_impl", False)
+                            else lin(p, seg))
+        return outs
 
     def __call__(self, params, x, train=True, rng=None):
         """x: (..., d_model). Tokens are routed in sharded groups

@@ -24,7 +24,8 @@ What is tunable per kernel:
   block's lane dim stays 128-aligned).
 - linear_attention: `chunk`, the VMEM-residency chunk of the causal kernel.
 - bidir_linear_attention: nothing — the fused kernel holds the whole
-  sequence resident, so the tuner only records its VMEM feasibility.
+  sequence resident, so the tuner only records its VMEM feasibility (at
+  n query and n_kv key/value rows, where a site gives `n_kv`).
 
 Winning configs persist in TUNE_kernels.json (``TuneTable.save``/``load``)
 keyed by exact kernel × geometry; `DeployPlan`/`BucketedViTEngine` thread

@@ -25,9 +25,11 @@ m = 1[x ≥ 0] ∈ {0,1} and b = 2m − 1,
 i.e. a masked add (popcount-style: accumulate only the rows the mask keeps)
 plus a shared column sum, never materializing the ±1 codes.
 
-Head dims are zero-masked up to the true d_k/d_v and sequence rows up to the
-true n, so the ops.py wrapper may pad to lane/sublane alignment without
-changing the Hamming kernel's `+d` offsets.
+Head dims are zero-masked up to the true d_k/d_v and k/v rows up to the true
+n_kv, so the ops.py wrapper may pad to lane/sublane alignment without
+changing the Hamming kernel's `+d` offsets. Queries and keys may come from
+token sets of different lengths (PVT's spatial-reduction attention: n_q
+tokens attend to n_kv reduced ones); the normaliser's offset is then d·n_kv.
 """
 from __future__ import annotations
 
@@ -47,21 +49,27 @@ MAX_FUSED_N = 4096
 
 def _make_kernel(dk_true: int, n_true: int):
     def kernel(q_ref, k_ref, v_ref, o_ref):
-        q = q_ref[0].astype(jnp.float32)              # (Np, dk_pad)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)              # (Np, dv_pad)
+        q = q_ref[0].astype(jnp.float32)              # (Nq_pad, dk_pad)
+        k = k_ref[0].astype(jnp.float32)              # (Nkv_pad, dk_pad)
+        v = v_ref[0].astype(jnp.float32)              # (Nkv_pad, dv_pad)
         n_pad, dk_pad = q.shape
+        nkv_pad = k.shape[0]
         dv_pad = v.shape[-1]
         # Binarize; zero the padded feature lanes so they drop out of dots.
         lane = jax.lax.broadcasted_iota(jnp.int32, (n_pad, dk_pad), 1)
         lane_valid = (lane < dk_true).astype(jnp.float32)
+        if nkv_pad == n_pad:
+            lane_valid_k = lane_valid
+        else:
+            lane_k = jax.lax.broadcasted_iota(jnp.int32, (nkv_pad, dk_pad), 1)
+            lane_valid_k = (lane_k < dk_true).astype(jnp.float32)
         bq = jnp.where(q >= 0, 1.0, -1.0) * lane_valid
-        bk = jnp.where(k >= 0, 1.0, -1.0) * lane_valid
-        # Zero padded sequence rows: their k/v must not enter the global sums
-        # (padded *query* rows produce garbage rows sliced off outside).
-        row_k = jax.lax.broadcasted_iota(jnp.int32, (n_pad, dk_pad), 0)
+        bk = jnp.where(k >= 0, 1.0, -1.0) * lane_valid_k
+        # Zero padded k/v rows: they must not enter the global sums (padded
+        # *query* rows produce garbage rows sliced off outside).
+        row_k = jax.lax.broadcasted_iota(jnp.int32, (nkv_pad, dk_pad), 0)
         bk = bk * (row_k < n_true).astype(jnp.float32)
-        row_v = jax.lax.broadcasted_iota(jnp.int32, (n_pad, dv_pad), 0)
+        row_v = jax.lax.broadcasted_iota(jnp.int32, (nkv_pad, dv_pad), 0)
         v = v * (row_v < n_true).astype(jnp.float32)
 
         d = jnp.float32(dk_true)
@@ -72,7 +80,7 @@ def _make_kernel(dk_true: int, n_true: int):
         # Phase 2: emit every output row against the finished accumulators.
         num = jnp.dot(bq, kv, preferred_element_type=jnp.float32)
         num += d * vsum                                             # broadcasts
-        den = jnp.sum(bq * ksum, axis=-1) + d * jnp.float32(n_true)  # (Np,)
+        den = jnp.sum(bq * ksum, axis=-1) + d * jnp.float32(n_true)  # (Nq_pad,)
         o_ref[0] = (num / (den[:, None] + 1e-6)).astype(o_ref.dtype)
 
     return kernel
@@ -81,26 +89,33 @@ def _make_kernel(dk_true: int, n_true: int):
 @functools.partial(jax.jit, static_argnames=("dk_true", "n_true", "interpret"))
 def bidir_binary_attention_pallas(q, k, v, *, dk_true=None, n_true=None,
                                   interpret=False):
-    """q, k: (G, N, Dk); v: (G, N, Dv) → (G, N, Dv). Non-causal.
+    """q: (G, Nq, Dk); k: (G, Nkv, Dk); v: (G, Nkv, Dv) → (G, Nq, Dv).
+    Non-causal. Nq and Nkv may differ (spatial-reduction attention: queries
+    over every token, keys and values over a reduced set); with Nq = Nkv
+    the kernel is the self-attention one.
 
-    dk_true / n_true: the unpadded head dim / sequence length (default Dk/N);
-    padded lanes and rows are masked out of the Hamming kernel inside VMEM so
-    the wrapper may pad to tile alignment freely.
+    dk_true / n_true: the unpadded head dim / number of k and v rows
+    (default Dk / Nkv); padded lanes and k/v rows are masked out of the
+    Hamming kernel inside VMEM, so the wrapper may pad to tile alignment
+    freely. Padded query rows need no mask: their output rows are sliced
+    off outside.
     """
     g, n, dk = q.shape
+    nkv = k.shape[1]
     dv = v.shape[-1]
     dk_true = dk if dk_true is None else int(dk_true)
-    n_true = n if n_true is None else int(n_true)
-    assert n <= MAX_FUSED_N, (
+    n_true = nkv if n_true is None else int(n_true)
+    assert max(n, nkv) <= MAX_FUSED_N, (
         f"fused bidirectional kernel holds the whole sequence in VMEM; "
-        f"N={n} > {MAX_FUSED_N} — use the chunked causal kernel dataflow")
+        f"N={max(n, nkv)} > {MAX_FUSED_N} — use the chunked causal kernel "
+        f"dataflow")
     return pl.pallas_call(
         _make_kernel(dk_true, n_true),
         grid=(g,),
         in_specs=[
             pl.BlockSpec((1, n, dk), lambda gg: (gg, 0, 0)),
-            pl.BlockSpec((1, n, dk), lambda gg: (gg, 0, 0)),
-            pl.BlockSpec((1, n, dv), lambda gg: (gg, 0, 0)),
+            pl.BlockSpec((1, nkv, dk), lambda gg: (gg, 0, 0)),
+            pl.BlockSpec((1, nkv, dv), lambda gg: (gg, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, n, dv), lambda gg: (gg, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, n, dv), v.dtype),
@@ -110,8 +125,8 @@ def bidir_binary_attention_pallas(q, k, v, *, dk_true=None, n_true=None,
 
 
 def bidir_binary_attention_xla(q, k, v):
-    """XLA inference twin of the fused kernel. q, k: (B, H, N, Dk); v: (B, H,
-    N, Dv) → (B, H, N, Dv).
+    """XLA inference twin of the fused kernel. q: (B, H, Nq, Dk); k: (B, H,
+    Nkv, Dk); v: (B, H, Nkv, Dv) → (B, H, Nq, Dv).
 
     No STE (nothing differentiates through serving), and the ±1 contractions
     use the sign trick (module docstring): the {0,1} masks are the only
@@ -121,7 +136,7 @@ def bidir_binary_attention_xla(q, k, v):
     """
     out_dtype = v.dtype
     d = q.shape[-1]
-    n = q.shape[-2]
+    n = k.shape[-2]
     v32 = v.astype(jnp.float32)
     mq = (q >= 0).astype(jnp.float32)
     mk = (k >= 0).astype(jnp.float32)

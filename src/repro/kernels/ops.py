@@ -216,8 +216,11 @@ def add_matmul_bitpacked(x, packed, impl=None, tune=None):
 # ---------------------------------------------------------------------------
 
 def binary_linear_attention_bidir(q, k, v, *, impl=None, tune=None):
-    """q, k: (B, H, N, Dk); v: (B, H, N, Dv) → (B, H, N, Dv). Non-causal —
-    the ViT/encoder serving form of the Hamming-kernel attention.
+    """q: (B, H, Nq, Dk); k: (B, H, Nkv, Dk); v: (B, H, Nkv, Dv) → (B, H, Nq,
+    Dv). Non-causal — the ViT/encoder serving form of the Hamming-kernel
+    attention. Nkv may differ from Nq (PVT's spatial-reduction attention:
+    keys and values over a reduced token set); each is padded and masked on
+    its own.
 
     Inference-only (no VJP; training uses repro.core.add_attention, whose STE
     machinery this path exists to skip). impl="xla" runs the sign-trick twin;
@@ -233,16 +236,17 @@ def binary_linear_attention_bidir(q, k, v, *, impl=None, tune=None):
     if impl == "xla":
         return _bidir.bidir_binary_attention_xla(q, k, v)
     b, h, n, dk = q.shape
+    nkv = k.shape[2]
     dv = v.shape[-1]
     qg = q.reshape(b * h, n, dk)
-    kg = k.reshape(b * h, n, dk)
-    vg = v.reshape(b * h, n, dv)
+    kg = k.reshape(b * h, nkv, dk)
+    vg = v.reshape(b * h, nkv, dv)
     # Lane-align head dims and sublane-align N; the kernel masks both.
     qp = _pad_to(_pad_to(qg, 128, 2), 8, 1)
     kp = _pad_to(_pad_to(kg, 128, 2), 8, 1)
     vp = _pad_to(_pad_to(vg, 128, 2), 8, 1)
     out = _bidir.bidir_binary_attention_pallas(
-        qp, kp, vp, dk_true=dk, n_true=n, interpret=(impl == "interpret"))
+        qp, kp, vp, dk_true=dk, n_true=nkv, interpret=(impl == "interpret"))
     return out[:, :n, :dv].reshape(b, h, n, dv)
 
 

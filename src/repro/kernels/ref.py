@@ -31,7 +31,9 @@ def add_matmul_ref(x, b, out_dtype=None):
 def binary_linear_attention_ref(q, k, v, causal=True):
     """Naive quadratic oracle of the Hamming-kernel linear attention.
 
-    q, k: (B, H, N, Dk) float; v: (B, H, N, Dv).
+    q: (B, H, Nq, Dk) float; k: (B, H, Nkv, Dk); v: (B, H, Nkv, Dv). Causal
+    needs Nq = Nkv; non-causal takes any Nkv (keys and values over a reduced
+    token set, as PVT's spatial-reduction attention has them).
     sim(i,j) = (b_qi . b_kj + d) / (2d); out_i = sum_j sim v_j / sum_j sim.
     The (2d) cancels; this oracle keeps the raw (b.b + d) weights.
     """
@@ -41,6 +43,7 @@ def binary_linear_attention_ref(q, k, v, causal=True):
     bk = jnp.where(k >= 0, 1.0, -1.0)
     scores = jnp.einsum("bhnd,bhmd->bhnm", bq, bk) + d
     if causal:
+        assert k.shape[-2] == n, (n, k.shape[-2])
         mask = jnp.tril(jnp.ones((n, n)))
         scores = scores * mask
     out = jnp.einsum("bhnm,bhme->bhne", scores, v.astype(jnp.float32))

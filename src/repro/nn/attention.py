@@ -155,19 +155,23 @@ class Attention:
     accepts_impl = True
 
     # -- shared projection helpers ------------------------------------------
-    def _qkv(self, params, x, positions, impl=None, tune=None):
+    def _qkv(self, params, x, positions, impl=None, tune=None, kv=None):
         """Returns (q, k, v, vraw); vraw is the pre-DWConv V projection —
-        the raw stream the decode conv cache is warmed from."""
+        the raw stream the decode conv cache is warmed from. q comes from
+        x, k and v from `kv` (default x): PVT's spatial-reduction attention
+        projects them from a reduced token set."""
         b, n, _ = x.shape
+        kv = x if kv is None else kv
+        nkv = kv.shape[1]
         q = L.call_linear(self.q_proj, params["q"], x, impl,
                           tune).reshape(b, n, self.h, self.dh)
-        k = L.call_linear(self.k_proj, params["k"], x, impl,
-                          tune).reshape(b, n, self.hkv, self.dh)
-        vraw = L.call_linear(self.v_proj, params["v"], x, impl, tune)
+        k = L.call_linear(self.k_proj, params["k"], kv, impl,
+                          tune).reshape(b, nkv, self.hkv, self.dh)
+        vraw = L.call_linear(self.v_proj, params["v"], kv, impl, tune)
         vflat = vraw
         if self.dwconv is not None:
             vflat = vflat + self.dwconv(params["dwconv"], vflat)
-        v = vflat.reshape(b, n, self.hkv, self.dh)
+        v = vflat.reshape(b, nkv, self.hkv, self.dh)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         if self.qk_norm:
             q = self.q_norm(params["q_norm"], q)
@@ -186,15 +190,16 @@ class Attention:
         return fn(q), fn(k)
 
     # -- full-sequence forward (train / prefill) -----------------------------
-    def __call__(self, params, x, positions=None, train=True):
+    def __call__(self, params, x, positions=None, train=True, kv=None):
+        """kv: the token set k and v are projected from (default x)."""
         cfg = self.cfg
-        q, k, v, _ = self._qkv(params, x, positions)
+        q, k, v, _ = self._qkv(params, x, positions, kv=kv)
         b, _, n, _ = q.shape
         if self.mode == "dense":
             out = softmax_attention(q, k, v, causal=self.causal,
                                     window=self.window,
                                     softcap=cfg.attn_logit_softcap,
-                                    chunk=min(512, n))
+                                    chunk=min(512, k.shape[2]))
         else:
             g = self.h // self.hkv
             kf = _repeat_kv(k, g)
@@ -208,20 +213,23 @@ class Attention:
         return self.o_proj(params["o"], out)
 
     # -- inference -----------------------------------------------------------
-    def infer(self, params, x, positions=None, impl=None, tune=None):
+    def infer(self, params, x, positions=None, impl=None, tune=None,
+              kv=None):
         """Serving forward. For the encoder binary-linear mode (the ViT path)
         this routes through the fused bidirectional Hamming-attention op
         (kernels.ops.binary_linear_attention_bidir): one pass accumulating
         KV/ksum then emitting outputs, no STE machinery. impl/tune arrive
         threaded from the serving engine (never a process global); every
         other mode falls back to the train=False forward, whose kernels have
-        no impl selection."""
+        no impl selection. kv: the token set k and v are projected from
+        (default x; PVT passes its spatially reduced tokens)."""
         if self.mode != "binary_linear" or self.causal:
-            return self(params, x, positions=positions, train=False)
+            return self(params, x, positions=positions, train=False, kv=kv)
         from repro.kernels import ops
 
         b, n, _ = x.shape
-        q, k, v, _ = self._qkv(params, x, positions, impl=impl, tune=tune)
+        q, k, v, _ = self._qkv(params, x, positions, impl=impl, tune=tune,
+                               kv=kv)
         g = self.h // self.hkv
         kf = _repeat_kv(k, g)
         vf = _repeat_kv(v, g)

@@ -162,15 +162,25 @@ def dispatch_infer(xg, expert_idx, gate, caps):
     # spent on values nothing consumes. (The training `dispatch` zero-fills
     # because its scatter-add combine touches every buffer row.)
     info = {"expert": expert_idx, "pos": pos, "keep": keep, "gate": gate,
-            "caps": tuple(caps)}
+            "caps": tuple(caps), "src": flat_src}
     return buf, info
 
 
-def combine_infer(expert_outs, info):
+def redispatch_infer(yg, info):
+    """yg: (G, S, f) per-token rows of any width → (G, total, f), gathered
+    under the routing plan `dispatch_infer` made (same rows, same order): a
+    second pass through the experts of the tokens they already hold."""
+    g, s, f = yg.shape
+    return yg.reshape(g * s, f)[info["src"]].reshape(g, -1, f)
+
+
+def combine_infer(expert_outs, info, gated=True):
     """expert_outs: list of (G, cap_e, d) per-expert outputs in segment order
     → (G, S, d). Pure gathers: each token reads row `pos` of its expert's
-    segment (top-1 ⇒ exactly one contribution), scaled by gate·keep. Flat
-    single-gather per expert, same rationale as dispatch_infer."""
+    segment (top-1 ⇒ exactly one contribution), scaled by gate·keep, or by
+    keep alone where `gated` is False (hidden rows brought back to the token
+    grid between two expert linears). Flat single-gather per expert, same
+    rationale as dispatch_infer."""
     expert, pos, keep, gate = (info["expert"], info["pos"], info["keep"],
                                info["gate"])
     g, s = expert.shape
@@ -182,6 +192,8 @@ def combine_infer(expert_outs, info):
         got = out_e.reshape(g * cap_e, -1)[flat].reshape(g, s, -1)
         got = jnp.where((expert == e)[..., None], got, 0.0)
         y = got if y is None else y + got
+    if not gated:
+        return y * keep.astype(y.dtype)[..., None]
     w = (gate * keep.astype(gate.dtype)).astype(y.dtype)
     return y * w[..., None]
 

@@ -195,9 +195,15 @@ class MLP:
     # Shift-MLPs route through ShiftLinear; serving threads impl/tune here.
     accepts_impl = True
 
-    def __call__(self, params, x, impl=None, tune=None):
+    def __call__(self, params, x, impl=None, tune=None, mid=None):
+        """mid: a function of the hidden activations (..., N, d_ff) that
+        takes the activation's place (PVT's Mix-FFN: a depthwise conv over
+        the token grid, then GELU); ungated MLPs only."""
         h = call_linear(self.up, params["up"], x, impl, tune)
-        if self.gated:
+        if mid is not None:
+            assert not self.gated, "mid replaces the activation of an ungated MLP"
+            h = mid(h)
+        elif self.gated:
             h = self.act(call_linear(self.gate, params["gate"], x,
                                      impl, tune)) * h
         else:

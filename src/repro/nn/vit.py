@@ -2,11 +2,12 @@
 reproduction experiments (sensitivity Tab. 2, MoE routing Fig. 6, LL-loss
 Tab. 7) on synthetic image-classification tasks.
 
-A compact PVT/DeiT-style encoder: patchify (linear on flattened patches) →
+A DeiT-style encoder: patchify (linear on flattened patches) →
 bidirectional transformer blocks whose attention / projections / MLPs follow
 the ShiftAddPolicy (exactly the paper's reparameterization surface) → mean
 pool → classifier head. `convert_from` implements the paper's two-stage
-reparameterization from a pretrained dense ViT's params.
+reparameterization from a pretrained dense ViT's params. The paper's
+pyramid backbone, PVTv2, is `nn/pvt.py`.
 """
 from __future__ import annotations
 
@@ -64,6 +65,12 @@ class ShiftAddViT:
         from repro.nn.layers import make_norm
         self.final_norm = make_norm("layernorm", cfg.d_model, 1e-6, dt, jnp.float32)
         self.head = Dense(cfg.d_model, cfg.n_classes, dtype=dt)
+
+    @property
+    def token_counts(self) -> tuple:
+        """Per-image token counts the MoE feeds route (the serving engine
+        warms their capacity plans): every block sees the patch grid."""
+        return (self.cfg.n_patches,)
 
     def init(self, key):
         ks = jax.random.split(key, len(self.blocks) + 3)

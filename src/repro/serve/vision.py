@@ -68,7 +68,12 @@ DEFAULT_BUCKETS = (1, 8, 32)
 class BucketedViTEngine:
     """Pads variable-size image batches into jit-cached bucket shapes.
 
-    model/params: a ShiftAddViT and its (possibly convert_from'd) params.
+    model/params: an image classifier and its (possibly convert_from'd)
+    params — `nn.vit.ShiftAddViT` or `nn.pvt.PVTv2`. The engine needs of the
+    model `infer(params, images, impl=, tune=)`, `prepare_inference(params,
+    impl=, token_counts=, tune=)`, `token_counts` (the per-image token
+    counts its MoE feeds route, warmed at build time) and `cfg.image_size`,
+    `cfg.in_channels`, `cfg.n_classes`.
     buckets: allowed batch sizes, ascending. Requests larger than the biggest
     bucket are split into max-bucket chunks, so any request size is served.
     freeze: build a core.deploy DeployPlan at engine construction (decode
@@ -88,7 +93,7 @@ class BucketedViTEngine:
     from here rather than re-declaring their own set.
     """
 
-    def __init__(self, model: ShiftAddViT, params, buckets=DEFAULT_BUCKETS,
+    def __init__(self, model, params, buckets=DEFAULT_BUCKETS,
                  freeze=True, impl=None, tune=None, mesh=None):
         from repro.kernels import ops
 
@@ -152,12 +157,13 @@ class BucketedViTEngine:
                                  out_specs=rows, check_vma=False)
         if freeze:
             # The MoE dispatch routes one group per image row, so the only
-            # token count it ever plans capacity for is the per-image patch
-            # count — identical across buckets (a bucket changes how many
-            # rows are vmapped over, never a row's capacity split).
+            # token counts it ever plans capacity for are the model's
+            # per-image ones (the patch count; one per stage of a pyramid)
+            # — identical across buckets (a bucket changes how many rows
+            # are vmapped over, never a row's capacity split).
             self.plan = model.prepare_inference(
-                params, impl=self.impl,
-                token_counts=(model.cfg.n_patches,), tune=tune)
+                params, impl=self.impl, token_counts=model.token_counts,
+                tune=tune)
             run_params = self.plan.params
             impl_, tune_ = self.impl, self.tune
 
